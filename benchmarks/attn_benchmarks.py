@@ -135,7 +135,6 @@ import json, os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 import numpy as np
 from repro.kernels.flash_attention import ring_flash_attention
 from repro.dist.hlo_analysis import no_s2_scores, weighted_collectives
@@ -151,8 +150,8 @@ def attn(q, k, v):
         q, k, v, axis_name="seq", axis_size=n_sh, causal=True,
         block_q=BLK, block_k=BLK)
 
-f = jax.jit(shard_map(attn, mesh=mesh, in_specs=(spec, spec, spec),
-                      out_specs=spec, check_rep=False))
+f = jax.jit(jax.shard_map(attn, mesh=mesh, in_specs=(spec, spec, spec),
+                          out_specs=spec, check_vma=False))
 args = [jax.ShapeDtypeStruct((B, S, H, HD), jnp.float32),
         jax.ShapeDtypeStruct((B, S, KV, HD), jnp.float32),
         jax.ShapeDtypeStruct((B, S, KV, HD), jnp.float32)]
@@ -305,7 +304,8 @@ def bench_ring_500k(*, seq: int = 524_288, block: int = 4096,
     is larger than the executed cells' 512 to keep the per-shard q-block
     unroll (S/8/block scans x 8 ring steps) tractable to trace."""
     r = _subprocess_json(_RING_SCRIPT, "RING_BENCH",
-                         {"ATTN_S": str(seq), "ATTN_CHUNK": str(block)},
+                         {"ATTN_S": str(seq), "ATTN_CHUNK": str(block),
+                          "JAX_PLATFORMS": "cpu"},
                          timeout)
     name = f"attn[ring_flash,S={seq},seq_mesh=8,lower_only]"
     if "error" in r:
@@ -369,7 +369,9 @@ def main() -> None:
     ap.add_argument("--jsonl", default=None, metavar="PATH",
                     help="also write the rows as JSON lines (CI artifact)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()  # the device-side children inherit it
     print("name,us_per_call,derived", flush=True)
     json_rows: list = []
     if args.record_baseline:

@@ -80,7 +80,8 @@ def run_combo(arch: str, shape: str, mesh: str, out: str,
             cmd.append("--require-seq-sharded")
         if shape in A2A_GATED.get(arch, ()):
             cmd.append("--require-alltoall")
-    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"),
+               JAX_PLATFORMS="cpu")
     t0 = time.time()
     try:
         proc = subprocess.run(
@@ -104,7 +105,8 @@ def run_wire_ratio(arch: str, out: str, timeout: int = 3600,
         "--arch", arch, "--shape", "train_512", "--wire-ratio",
         "--downlink", downlink, "--out", out,
     ]
-    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"),
+               JAX_PLATFORMS="cpu")
     t0 = time.time()
     try:
         proc = subprocess.run(

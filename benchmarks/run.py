@@ -69,8 +69,9 @@ def bench_wire_ratio(timeout: int = 1800, downlink: str = "quant") -> list[tuple
     import subprocess
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    # a 512-host-device dry run: CPU only, never the parent's accelerator
     env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
-               BENCH_DOWNLINK=downlink)
+               BENCH_DOWNLINK=downlink, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     try:
         proc = subprocess.run(
@@ -146,7 +147,8 @@ def bench_moe_alltoall(timeout: int = 1800) -> list[tuple]:
     import subprocess
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"))
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     try:
         proc = subprocess.run(
@@ -222,6 +224,7 @@ def main() -> None:
     import argparse
 
     from benchmarks import fl_benchmarks as flb
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.obs import default_ledger, maybe_trace
 
     ap = argparse.ArgumentParser()
@@ -230,6 +233,7 @@ def main() -> None:
     ap.add_argument("--xprof", default=None, metavar="DIR",
                     help="capture a profiler trace of the kernel microbench")
     args = ap.parse_args()
+    enable_compile_cache()
     ledger = default_ledger(args.ledger)
     ledger.run_header(name="benchmarks.run", entry="run.main")
 
@@ -239,6 +243,18 @@ def main() -> None:
     def emit(rows):
         for name, us, derived in rows:
             print(f"{name},{us:.1f},{derived}", flush=True)
+
+    # Child processes first: the attention children execute on the
+    # device, and a chip belongs to one process at a time, so this parent
+    # touches no backend until they have exited (the dry-run children pin
+    # the CPU platform themselves). Attention rows are CPU-sized cells;
+    # the full matrix incl. the 128k cell and the 500k ring record is
+    #   PYTHONPATH=src python benchmarks/attn_benchmarks.py --json
+    from benchmarks import attn_benchmarks as attnb
+
+    emit(attnb.bench_flash_attention(quick=True, record_json=False))
+    emit(bench_wire_ratio())
+    emit(bench_moe_alltoall())
 
     from benchmarks import sim_benchmarks as simb
 
@@ -260,15 +276,6 @@ def main() -> None:
     # which also records rows into BENCH_sim.json)
     emit(simb.bench_baseline_energy(u=64, n_rounds=10, batch_size=8,
                                     n_channels=8, scenario="single_bs"))
-    emit(bench_wire_ratio())
-    emit(bench_moe_alltoall())
-    # chunked vs flash tokens/s (CPU-sized cells; the full matrix incl.
-    # the 128k cell and the 500k ring lower+compile record is
-    #   PYTHONPATH=src python benchmarks/attn_benchmarks.py --json
-    # which also records rows into BENCH_sim.json)
-    from benchmarks import attn_benchmarks as attnb
-
-    emit(attnb.bench_flash_attention(quick=True, record_json=False))
     emit(simb.bench_sim_vs_object(u=8, n_rounds=10))
     emit(flb.bench_v_tradeoff(task="tiny", n_rounds=10))
     emit(flb.bench_task("femnist", betas=(300.0,), n_rounds=6))

@@ -125,14 +125,15 @@ def bench_fleet_scale(
     )
     rows.append((
         f"sim_build[{tag}]", t_build.seconds * 1e6,
-        f"z={sim.z};n_max={int(sim.fleet.x.shape[1])};policy={policy_mode}"
+        f"z={sim.z};n_max={sim.fleet.n_max};policy={policy_mode}"
         f";A={sim.channel.n_aps};assoc={sim.channel.association}",
     ))
 
     keys, ridx = sim._scan_xs(n_rounds)
     carry = sim._init_carry()
     with timed_phase("lower", led, tag=tag, rounds=n_rounds) as t_lower:
-        lowered = sim._scan_fn(with_eval).lower(sim._dyn, carry, keys, ridx)
+        lowered = sim._scan_fn(with_eval).lower(sim.data(), sim._dyn, carry,
+                                                keys, ridx)
     hlo_bytes = len(lowered.as_text())
     led.hlo_event(f"sim_lower[{tag}]", {"hlo_bytes": hlo_bytes},
                   rounds=n_rounds)
@@ -150,7 +151,8 @@ def bench_fleet_scale(
 
     with maybe_trace(xprof):
         with timed_phase("run", led, tag=tag, rounds=n_rounds) as t_run:
-            (flat, *_), out = compiled(sim._dyn, carry, keys, ridx)
+            (flat, *_), out = compiled(sim.data(), sim._dyn, carry, keys,
+                                       ridx)
             jax.block_until_ready(flat)
     run_s = t_run.seconds
     import numpy as np
@@ -331,9 +333,10 @@ def bench_fault_overhead(
         keys, ridx = sim._scan_xs(n_rounds)
         carry = sim._init_carry()
         compiled = sim._scan_fn(True).lower(
-            sim._dyn, carry, keys, ridx).compile()
+            sim.data(), sim._dyn, carry, keys, ridx).compile()
         with timed_phase("run", led, tag=tag, rounds=n_rounds) as t_run:
-            (flat, *_), out = compiled(sim._dyn, carry, keys, ridx)
+            (flat, *_), out = compiled(sim.data(), sim._dyn, carry, keys,
+                                       ridx)
             jax.block_until_ready(flat)
         results[label] = (
             t_run.seconds,
@@ -473,7 +476,9 @@ def main() -> None:
                          "under --outage-p outages) instead of the "
                          "scaling bench")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.obs import default_ledger
+    enable_compile_cache()
     ledger = default_ledger(args.ledger)
     print("name,us_per_call,derived", flush=True)
     json_rows: list | None = [] if args.json else None

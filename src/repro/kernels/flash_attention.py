@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.stochastic_quant import resolve_interpret
 from repro.obs.profile import scope as _profile_scope
 
 NEG_INF = -1e30  # finite, matching dense_attention (no inf - inf NaNs)
@@ -184,7 +185,7 @@ def flash_attention_pallas(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     block_q: int = DEFAULT_BLOCK, block_k: int = DEFAULT_BLOCK,
     causal: bool = True, window: int = 0,
-    interpret: bool = True, with_lse: bool = False,
+    interpret: bool | None = None, with_lse: bool = False,
 ):
     """q: (B, S, H, hd); k/v: (B, T, KV, hd); H a multiple of KV.
 
@@ -238,7 +239,7 @@ def flash_attention_pallas(
                 jax.ShapeDtypeStruct((b * h, s), jnp.float32),
                 jax.ShapeDtypeStruct((b * h, s), jnp.float32),
             ],
-            interpret=interpret,
+            interpret=resolve_interpret(interpret),
         )(qf, kf, vf)
     out = o.reshape(b, h, s, hd).transpose(0, 2, 1, 3).astype(q.dtype)
     if with_lse:
@@ -509,9 +510,9 @@ def flash_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     block_q: int = DEFAULT_BLOCK, block_k: int = DEFAULT_BLOCK,
     causal: bool = True, window: int = 0, impl: str = "xla",
-    interpret: bool = True, with_lse: bool = False,
+    interpret: bool | None = None, with_lse: bool = False,
 ):
-    """Dispatch: ``impl='pallas'`` (TPU kernel; interpret-mode on CPU)
+    """Dispatch: ``impl='pallas'`` (TPU kernel; interpret-mode off-TPU)
     or ``impl='xla'`` (blockwise twin — the default off-TPU)."""
     if impl == "pallas":
         return flash_attention_pallas(

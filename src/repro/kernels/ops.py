@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas quantization kernels.
 
 Handles: pytree flatten -> (M, 128) tile padding -> kernel -> unflatten.
-``interpret`` defaults to True off-TPU (the container is CPU-only; the
-kernels target TPU BlockSpec tiling and are validated in interpret mode).
+``interpret=None`` resolves by platform (``sq.resolve_interpret``): compiled
+Mosaic kernels on a TPU, the Pallas interpreter elsewhere.
 """
 from __future__ import annotations
 
@@ -16,14 +16,6 @@ from repro.kernels import stochastic_quant as sq
 
 Pytree = Any
 LANES = sq.LANES
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def default_interpret() -> bool:
-    return not _on_tpu()
 
 
 def pad_to_tiles(flat: jax.Array, block_m: int = sq.BLOCK_M) -> tuple[jax.Array, int]:
@@ -61,11 +53,10 @@ def quantize_flat(
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """1-D fp32 -> (idx u8 (M,128), signs u8, scale fp32). Stochastic.
     The caller keeps the original length (``flat.shape[0]``) for unpadding."""
-    interp = default_interpret() if interpret is None else interpret
     tiled, _ = pad_to_tiles(flat)
     scale = jnp.max(jnp.abs(flat))
     rbits = jax.random.bits(key, tiled.shape, jnp.uint32)
-    idx, signs = sq.quantize(tiled, rbits, scale, q_bits, interpret=interp)
+    idx, signs = sq.quantize(tiled, rbits, scale, q_bits, interpret=interpret)
     return idx, signs, scale
 
 
@@ -74,8 +65,7 @@ def dequantize_flat(
     idx: jax.Array, signs: jax.Array, scale: jax.Array, q_bits: int, n: int,
     *, interpret: bool | None = None,
 ) -> jax.Array:
-    interp = default_interpret() if interpret is None else interpret
-    out = sq.dequantize(idx, signs, scale, q_bits, interpret=interp)
+    out = sq.dequantize(idx, signs, scale, q_bits, interpret=interpret)
     return out.reshape(-1)[:n]
 
 
@@ -98,6 +88,5 @@ def aggregate_uploads(
 ) -> jax.Array:
     """Server-side fused dequant + weighted sum (paper eq. 2).
     idx/signs: (K, M, 128); returns (M*128,) fp32 flat aggregate."""
-    interp = default_interpret() if interpret is None else interpret
-    out = sq.aggregate(idx, signs, scales, weights, q_bits, interpret=interp)
+    out = sq.aggregate(idx, signs, scales, weights, q_bits, interpret=interpret)
     return out.reshape(-1)

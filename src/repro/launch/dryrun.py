@@ -3,8 +3,12 @@ os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")
 )
-# NOTE: the two lines above MUST run before any other import (jax locks the
-# device count at first init). Do not move them.
+# a dry run compiles for 512 host devices: pin the CPU platform, so on a
+# machine with an accelerator the mesh is not built from its chips (and
+# this process never holds one)
+os.environ["JAX_PLATFORMS"] = "cpu"
+# NOTE: the lines above MUST run before any other import (jax locks the
+# device count and platform at first init). Do not move them.
 
 """Multi-pod dry-run driver.
 

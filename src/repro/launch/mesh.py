@@ -37,8 +37,7 @@ def mesh_label(mesh) -> str:
 
 
 def _make_mesh(shape, axes):
-    # jax >= 0.5 takes axis_types; 0.4.x has neither the kwarg nor the
-    # AxisType enum (meshes are Auto-typed implicitly). Support both.
+    # Auto-typed axes: shardings propagate through jit.
     # When the shape uses fewer devices than the backend exposes (e.g. a
     # 128-chip 4D config under the 512-device XLA flag), slice the leading
     # devices in row-major order — the same order jax.make_mesh uses.
@@ -52,11 +51,8 @@ def _make_mesh(shape, axes):
         return jax.sharding.Mesh(
             np.asarray(devices[:n]).reshape(shape), axes
         )
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(
-        shape, axes, axis_types=(axis_type.Auto,) * len(axes)
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
     )
 
 

@@ -220,7 +220,7 @@ def flash_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     block_q: int = 512, block_k: int = 512,
     causal: bool = True, window: int = 0,
-    impl: str = "xla", interpret: bool = True,
+    impl: str = "xla", interpret: bool | None = None,
 ) -> jax.Array:
     """Blockwise flash attention (see ``kernels/flash_attention.py``).
 

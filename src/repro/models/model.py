@@ -18,8 +18,6 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.activations import current_activation_plan, shard_act
@@ -168,15 +166,15 @@ def _flash_dispatch(
         if isinstance(ent, str):
             n = plan.axis_size(ent)
             if n > 1 and s % (n * blk) == 0:
-                # Fully-manual shard_map (jax 0.4.37's partial-auto mode
-                # rejects/crashes on the manual-subgroup collectives this
-                # body needs), so every mesh axis gets an explicit spec:
+                # Fully-manual shard_map (the form the --require-flash
+                # dry-run gate validated; see kernels/README.md), so every
+                # mesh axis gets an explicit spec:
                 #   * heads ride the model axis only when BOTH the query
                 #     and KV head counts divide it — contiguous head
                 #     blocks then align with whole GQA groups, keeping
                 #     the in-kernel head->kv mapping local;
                 #   * batch follows the plan's progressive dp rule;
-                #   * axes in no spec carry replicated data (check_rep
+                #   * axes in no spec carry replicated data (check_vma
                 #     off: the body is deterministic per shard).
                 msz = plan.axis_size("model")
                 heads_ent = (
@@ -198,10 +196,10 @@ def _flash_dispatch(
                         window=window, shard_id=ids[0],
                     )
 
-                return shard_map(
+                return jax.shard_map(
                     ring_body, mesh=plan.mesh,
                     in_specs=(spec, spec, spec, P(ent)), out_specs=spec,
-                    check_rep=False,
+                    check_vma=False,
                 )(q, k, v, jnp.arange(n, dtype=jnp.int32))
     return layers.flash_attention(
         q, k, v, block_q=blk, block_k=blk, causal=causal, window=window

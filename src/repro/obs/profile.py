@@ -15,7 +15,9 @@ trace is being captured.
 ``maybe_trace`` gates ``jax.profiler.trace`` on a directory argument so
 CLIs can expose ``--xprof DIR`` without branching: None is a no-op
 context. Capture it around the steady-state region only (after compile),
-so the profile shows round execution, not tracing.
+so the profile shows round execution, not tracing. A requested trace
+that cannot be started or written raises: a run asked for a profile
+must not exit 0 without one.
 """
 from __future__ import annotations
 
@@ -42,27 +44,23 @@ def annotate(name: str):
 def maybe_trace(trace_dir: Optional[str]) -> Iterator[None]:
     """``jax.profiler.trace(dir)`` when a directory is given, else no-op.
 
-    Degrades gracefully (warn, continue) if the profiler backend is
-    unavailable in the container — capturing a profile is never allowed
-    to take the benchmark down with it.
+    A profiler that fails to start or stop raises (``RuntimeError``
+    naming the directory, chained to the cause).
     """
     if not trace_dir:
         yield
         return
     try:
         jax.profiler.start_trace(trace_dir)
-        started = True
-    except Exception as e:  # noqa: BLE001
-        print(f"# xprof capture unavailable ({type(e).__name__}: {e})",
-              flush=True)
-        started = False
+    except Exception as e:  # noqa: BLE001 — re-raised with the directory
+        raise RuntimeError(f"xprof capture to {trace_dir!r} failed to "
+                           f"start: {e}") from e
     try:
         yield
     finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-                print(f"# xprof trace written to {trace_dir}", flush=True)
-            except Exception as e:  # noqa: BLE001
-                print(f"# xprof stop failed ({type(e).__name__}: {e})",
-                      flush=True)
+        try:
+            jax.profiler.stop_trace()
+        except Exception as e:  # noqa: BLE001 — re-raised with the directory
+            raise RuntimeError(f"xprof capture to {trace_dir!r} failed to "
+                               f"stop: {e}") from e
+        print(f"# xprof trace written to {trace_dir}", flush=True)

@@ -27,7 +27,11 @@ every round inside a single jitted ``lax.scan``:
 
 No per-client Python objects exist at run time: the fleet is four stacked
 arrays, the decision bookkeeping is (U,)-vectorized, and the per-round
-training/wire work is (S,)-compacted. ``run_host_policy`` is the per-round
+training/wire work is (S,)-compacted. The fleet arrays and the test set
+enter every jitted program as ARGUMENTS (``FleetSim.data``), never as
+closed-over constants: they stay on the device once placed, and the
+lowered program's size does not grow with the fleet's bytes (gated in
+tests/test_tpu_compile.py). ``run_host_policy`` is the per-round
 fallback engine that lets the host-side GA controller (``QCCFController``)
 or any ``repro.fl`` Policy drive the same compiled (and equally compacted)
 round execution when the closed-form fast path is not wanted; it replays
@@ -45,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.flatten_util import ravel_pytree
+from jax.sharding import PartitionSpec as P
 
 from repro.core import bounds
 from repro.core import quantization as core_quant
@@ -321,10 +326,11 @@ class FleetSim:
         fleet: Fleet,
         init_params: Pytree,
         loss_fn,
-        eval_fn,                    # traced (flat_params) -> (acc, loss)
+        eval_fn,                    # traced (flat_params, test) -> (acc, loss)
         channel: SimChannel,
         sysp: SystemParams,
         *,
+        test_set: dict,             # {"x", "y"} arrays eval_fn scores on
         eps1: float,
         eps2: float,
         v_weight: float = 100.0,
@@ -351,6 +357,8 @@ class FleetSim:
         self.fleet = fleet
         self.loss_fn = loss_fn
         self.eval_fn = eval_fn
+        self.test_set = {"x": jnp.asarray(test_set["x"]),
+                         "y": jnp.asarray(test_set["y"])}
         self.channel = channel
         self.sysp = sysp
         self.eps1, self.eps2 = float(eps1), float(eps2)
@@ -406,6 +414,22 @@ class FleetSim:
         if self.faults.enabled:
             self._dyn["faults"] = jnp.asarray(self.faults.dyn_vector())
         self._compiled: dict = {}
+        # set by shard_clients: the mesh, and the mesh axes the fleet's
+        # client rows are sharded over (None: not sharded)
+        self._mesh = None
+        self._client_axes = None
+
+    def data(self) -> dict:
+        """The device-resident jit arguments every compiled program takes
+        first: the stacked fleet arrays and the test set. Passing them (not
+        closing over them) keeps the lowered program independent of the
+        fleet's bytes; ``shard_clients`` re-places them."""
+        return {"x": self.fleet.x, "y": self.fleet.y,
+                "n_samples": self.fleet.n_samples, "test": self.test_set}
+
+    def _traced_fleet(self, data: dict) -> Fleet:
+        return dataclasses.replace(self.fleet, x=data["x"], y=data["y"],
+                                   n_samples=data["n_samples"])
 
     # ------------------------------------------------------------ round body
 
@@ -415,16 +439,22 @@ class FleetSim:
         One code path for every active-set size: the tiled Pallas kernel
         accumulates over its client grid axis, so there is no small-K
         static-unroll limit and no dense ``(U, Zpad)`` einsum fallback.
-        The planes arrive Zpad-shaped from ``_quantize_wire``.
+        The planes arrive Zpad-shaped from ``_quantize_wire``. On a
+        client-sharded mesh (``shard_clients``) the kernel runs under
+        ``shard_map`` with every operand replicated: XLA cannot partition a
+        Mosaic kernel, and the S slot planes are small next to the fleet.
         """
         s = idx.shape[0]
-        out = sq.aggregate(
+        agg = functools.partial(sq.aggregate, block_m=self.block_m)
+        if self._mesh is not None:
+            agg = jax.shard_map(agg, mesh=self._mesh, in_specs=P(),
+                                out_specs=P(), check_vma=False)
+        out = agg(
             idx.reshape(s, -1, LANES),
             signs.reshape(s, -1, LANES),
             theta,
             w_slot,
             jnp.maximum(q_slot, 1),
-            block_m=self.block_m,
         )
         return out.reshape(-1)
 
@@ -458,7 +488,7 @@ class FleetSim:
                    / (4.0 * levels**2)).astype(jnp.float32)
         return bcast, dl_next
 
-    def _round_body(self, dyn, carry, xs, with_eval: bool):
+    def _round_body(self, data, dyn, carry, xs, with_eval: bool):
         flat, g_sq, sigma_sq, theta_max, lam1, lam2 = carry[:6]
         tail = 6
         dl_prev = None
@@ -488,7 +518,8 @@ class FleetSim:
         )
         g_n = g_sq / jnp.maximum(jnp.mean(g_sq), 1e-12)
         s_n = sigma_sq / jnp.maximum(jnp.mean(sigma_sq), 1e-12)
-        d_sizes = self.fleet.n_samples.astype(jnp.float32)
+        fleet = self._traced_fleet(data)
+        d_sizes = fleet.n_samples.astype(jnp.float32)
         mode = self.policy_mode
         mcfg = self.metrics_cfg
         # static gate: GA fitness taps only exist in the trace when asked
@@ -560,7 +591,8 @@ class FleetSim:
         cid = jnp.maximum(slots, 0)
 
         params = self.unravel(flat)
-        x_s, y_s, n_s = gather_active(self.fleet, slots)
+        x_s, y_s, n_s = gather_active(fleet, slots, mesh=self._mesh,
+                                      client_axes=self._client_axes)
         stacked, g_obs, s_obs = fleet_local_sgd(
             self.loss_fn, sysp.tau, self.batch_size, params,
             x_s, y_s, n_s, self.lr, k_batch,
@@ -637,7 +669,7 @@ class FleetSim:
             lam2 = jnp.maximum(lam2 + dec.quant_term - dyn["eps"][1], 0.0)
 
         if with_eval:
-            acc, loss = self.eval_fn(new_flat)
+            acc, loss = self.eval_fn(new_flat, data["test"])
         else:
             acc, loss = jnp.float32(0.0), jnp.float32(0.0)
         out = {
@@ -721,24 +753,34 @@ class FleetSim:
         return keys, jnp.arange(n_rounds, dtype=jnp.int32)
 
     def _scan_fn(self, with_eval: bool):
-        """jit(run(dyn, carry, keys, ridx)) — the scenario's dynamic leaves
-        (``_dyn``: distances/hetero/eps) are jit ARGUMENTS, so re-running
-        with a structurally identical scenario's leaves hits the cache
-        (zero retrace)."""
+        """jit(run(data, dyn, carry, keys, ridx)) — the fleet + test set
+        (:meth:`data`) and the scenario's dynamic leaves (``_dyn``:
+        distances/hetero/eps) are jit ARGUMENTS, so re-running with a
+        structurally identical scenario's leaves hits the cache (zero
+        retrace) and nothing fleet-sized is baked into the program."""
 
-        def run(dyn, carry, keys, ridx):
+        def run(data, dyn, carry, keys, ridx):
             def body(c, xs):
-                return self._round_body(dyn, c, xs, with_eval)
+                return self._round_body(data, dyn, c, xs, with_eval)
 
             return jax.lax.scan(body, carry, (keys, ridx))
 
         return jax.jit(run)
 
+    def _jitted_scan(self, with_eval: bool):
+        """The one jitted scan per eval mode that :meth:`lower` and the run
+        paths share, so compiling a lowering ahead of time also serves the
+        next :meth:`run_compiled` of the same length."""
+        fn = self._compiled.get(with_eval)
+        if fn is None:
+            fn = self._compiled[with_eval] = self._scan_fn(with_eval)
+        return fn
+
     def lower(self, n_rounds: int, with_eval: bool = False):
         """Trace + lower the full n_rounds scan without executing (dry run)."""
         keys, ridx = self._scan_xs(n_rounds)
-        return self._scan_fn(with_eval).lower(
-            self._dyn, self._init_carry(), keys, ridx
+        return self._jitted_scan(with_eval).lower(
+            self.data(), self._dyn, self._init_carry(), keys, ridx
         )
 
     def _np_out(self, out) -> dict:
@@ -815,12 +857,11 @@ class FleetSim:
                                       ckpt_dir)
         if ckpt_dir is not None:
             raise ValueError("ckpt_dir requires segment=k (segmented scan)")
-        fn = self._compiled.get(with_eval)
-        if fn is None:
-            fn = self._compiled[with_eval] = self._scan_fn(with_eval)
+        fn = self._jitted_scan(with_eval)
         keys, ridx = self._scan_xs(n_rounds)
         t0 = time.perf_counter()
-        (flat, *_rest), out = fn(self._dyn, self._init_carry(), keys, ridx)
+        (flat, *_rest), out = fn(self.data(), self._dyn, self._init_carry(),
+                                 keys, ridx)
         jax.block_until_ready(out["energy"])
         run_s = time.perf_counter() - t0
         self.final_flat = flat
@@ -839,16 +880,15 @@ class FleetSim:
         once — at most two: k and the remainder)."""
         from repro import ckpt as repro_ckpt
 
-        fn = self._compiled.get(with_eval)
-        if fn is None:
-            fn = self._compiled[with_eval] = self._scan_fn(with_eval)
+        fn = self._jitted_scan(with_eval)
         keys, ridx = self._scan_xs(n_rounds)
         carry = self._init_carry() if carry is None else carry
         parts = [] if parts is None else list(parts)
         t0 = time.perf_counter()
         for b in range(start, n_rounds, segment):
             e = min(b + segment, n_rounds)
-            carry, out = fn(self._dyn, carry, keys[b:e], ridx[b:e])
+            carry, out = fn(self.data(), self._dyn, carry, keys[b:e],
+                            ridx[b:e])
             jax.block_until_ready(out["energy"])
             parts.append(self._np_out(out))
             if ckpt_dir is not None and e < n_rounds:
@@ -1025,7 +1065,7 @@ class FleetSim:
         u = self.fleet.n_clients
 
         @jax.jit
-        def exec_round(flat, slots, q_slot, wd_slot, key,
+        def exec_round(data, flat, slots, q_slot, wd_slot, key,
                        v_slot=None, f_slot=None, out_state=None):
             # identical key discipline to _round_body (k_ch unused: the
             # caller already drew the rates)
@@ -1035,7 +1075,9 @@ class FleetSim:
                 down_u = draw_outage(k_out, out_state, fv)
                 fade_hit_u, fade_mult_u = draw_fade(k_fade, u, fv)
             params = self.unravel(flat)
-            x_s, y_s, n_s = gather_active(self.fleet, slots)
+            x_s, y_s, n_s = gather_active(
+                self._traced_fleet(data), slots, mesh=self._mesh,
+                client_axes=self._client_axes)
             stacked, g_obs, s_obs = fleet_local_sgd(
                 self.loss_fn, self.sysp.tau, self.batch_size, params,
                 x_s, y_s, n_s, self.lr, k_batch,
@@ -1072,7 +1114,7 @@ class FleetSim:
                 exact_flat = new_flat
                 new_flat, dl_next = self._downlink_apply(key, new_flat, flat)
             if with_eval:
-                acc, loss = self.eval_fn(new_flat)
+                acc, loss = self.eval_fn(new_flat, data["test"])
             else:
                 acc, loss = jnp.float32(0.0), jnp.float32(0.0)
             out = (new_flat, g_obs, s_obs, theta, acc, loss)
@@ -1114,6 +1156,7 @@ class FleetSim:
         if channel == "host":
             assert self.host_channel is not None, "build with a host ChannelModel"
         exec_round = self._exec_fn(with_eval)
+        data = self.data()
         mcfg = self.metrics_cfg
         tap_mse = mcfg.enabled and mcfg.quant_mse
         dl_on = self.downlink.enabled
@@ -1223,7 +1266,7 @@ class FleetSim:
                     out_state=out_state_h,
                 )
             flat, g_obs, s_obs, theta, acc, loss, *extras = exec_round(
-                flat, jnp.asarray(slots, jnp.int32),
+                data, flat, jnp.asarray(slots, jnp.int32),
                 jnp.asarray(q_slot, jnp.int32),
                 jnp.asarray(d_slot if faults_on else w_slot, jnp.float32),
                 keys[n], **fault_kw,
@@ -1325,7 +1368,11 @@ class FleetSim:
         """Distribute the client axis over a mesh axis via the repro.dist
         logical-axis plan: the stacked fleet arrays are annotated with the
         ``clients`` logical name and the plan's rule table resolves it to
-        ``axis`` (divisibility-gated); computation follows the data."""
+        ``axis`` (divisibility-gated); computation follows the data. The
+        arrays are jit arguments and are re-placed here; the cached scans
+        are dropped because the slot gather and the aggregate kernel are
+        traced under ``shard_map`` over ``mesh`` (see :meth:`_aggregate`,
+        ``fleet.gather_active``)."""
         from repro.dist import sharding as shd
         from repro.dist.plan import make_plan
 
@@ -1337,7 +1384,8 @@ class FleetSim:
         self.fleet = dataclasses.replace(
             self.fleet, x=placed["x"], y=placed["y"], n_samples=placed["n"],
         )
-        # cached jitted scans captured the old fleet arrays at trace time
+        self._mesh = mesh
+        self._client_axes = specs["x"][0]
         self._compiled.clear()
 
 
@@ -1419,15 +1467,14 @@ def build_sim(
                                       alpha=alpha_dirichlet, seed=seed)
     fleet = build_fleet(datasets)
     test = make_test_set(img_task, n=n_test, seed=seed + 999)
-    test_x = jnp.asarray(test["x"])
-    test_y = jnp.asarray(test["y"])
 
     loss_fn = functools.partial(cnn.loss_fn, cnn_cfg)
     params = cnn.init_params(cnn_cfg, jax.random.PRNGKey(seed))
     _flat0, unravel = ravel_pytree(params)
 
-    def eval_fn(flat):
-        return cnn.eval_metrics(cnn_cfg, unravel(flat), test_x, test_y)
+    def eval_fn(flat, test_set):
+        return cnn.eval_metrics(cnn_cfg, unravel(flat), test_set["x"],
+                                test_set["y"])
 
     ch_params = scenario.channel if scenario is not None else ChannelParams(
         n_clients=n_clients, n_channels=n_channels
@@ -1469,7 +1516,7 @@ def build_sim(
         # convenience: "quant"/"delta"/"off" at default q_bits
         downlink = DownlinkConfig(mode=downlink)
     return FleetSim(
-        fleet, params, loss_fn, eval_fn, channel, sysp,
+        fleet, params, loss_fn, eval_fn, channel, sysp, test_set=test,
         eps1=eps1, eps2=eps2, v_weight=v_weight, lr=lr,
         batch_size=batch_size, q_cap=q_cap,
         block_m=block_m, seed=seed, host_channel=host_channel,

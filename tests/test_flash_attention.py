@@ -236,7 +236,6 @@ def test_merge_partials_equals_monolithic():
 _RING_SCRIPT = r"""
 import jax, numpy as np, jax.numpy as jnp
 from functools import partial
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.kernels.flash_attention import ring_flash_attention, flash_attention_xla
 from repro.kernels.ref import flash_attention_ref
@@ -251,8 +250,8 @@ v = 0.3 * jax.random.normal(kv, (1, 1024, 2, 32))
 for window in (0, 200):
     fn = partial(ring_flash_attention, axis_name="seq", axis_size=8,
                  block_q=64, block_k=64, causal=True, window=window)
-    ring = jax.jit(shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                             out_specs=spec, check_rep=False))(q, k, v)
+    ring = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                                 out_specs=spec, check_vma=False))(q, k, v)
     ref = flash_attention_ref(q, k, v, causal=True, window=window)
     err = float(jnp.max(jnp.abs(ring - ref)))
     assert err < 2e-5, (window, err)

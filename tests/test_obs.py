@@ -63,7 +63,7 @@ def test_telemetry_off_is_byte_identical():
         keys, ridx = sim._scan_xs(2)
         carry = sim._init_carry()
         texts[name] = sim._scan_fn(False).lower(
-            sim._dyn, carry, keys, ridx
+            sim.data(), sim._dyn, carry, keys, ridx
         ).as_text()
     assert texts["none"] == texts["off"], (
         "telemetry-off lowered HLO differs from the no-telemetry engine"
@@ -79,13 +79,14 @@ def test_telemetry_on_zero_retrace(telem_sim):
     fn = telem_sim._scan_fn(False)
     keys, ridx = telem_sim._scan_xs(2)
     carry = telem_sim._init_carry()
-    jax.block_until_ready(fn(telem_sim._dyn, carry, keys, ridx)[0][0])
+    data = telem_sim.data()
+    jax.block_until_ready(fn(data, telem_sim._dyn, carry, keys, ridx)[0][0])
     dyn2 = {
         "distances": telem_sim._dyn["distances"] * 1.5,
         "hetero": telem_sim._dyn["hetero"] + 0.25,
         "eps": telem_sim._dyn["eps"] * 0.5,
     }
-    jax.block_until_ready(fn(dyn2, carry, keys, ridx)[0][0])
+    jax.block_until_ready(fn(data, dyn2, carry, keys, ridx)[0][0])
     assert fn._cache_size() == 1, "telemetry taps retraced the scan"
 
 
@@ -339,3 +340,23 @@ def test_timed_phase_without_ledger():
     with timed_phase("bare") as t:
         pass
     assert t.seconds >= 0.0
+
+
+@pytest.mark.parametrize("phase", ["start", "stop"])
+def test_maybe_trace_raises_when_profiler_fails(tmp_path, monkeypatch, phase):
+    """A requested profile that cannot be captured fails the run instead
+    of letting it exit 0 without a trace; no directory is a no-op."""
+    from repro.obs import profile
+
+    def broken(*_a, **_k):
+        raise OSError("profiler backend unavailable")
+
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        broken if phase == "start" else lambda d: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        broken if phase == "stop" else lambda: None)
+    with pytest.raises(RuntimeError, match=f"failed to {phase}"):
+        with profile.maybe_trace(str(tmp_path / "xprof")):
+            pass
+    with profile.maybe_trace(None):
+        pass

@@ -212,13 +212,14 @@ def test_zero_retrace_across_dyn_leaves():
     fn = sim._scan_fn(False)
     keys, ridx = sim._scan_xs(2)
     carry = sim._init_carry()
-    jax.block_until_ready(fn(sim._dyn, carry, keys, ridx)[0][0])
+    data = sim.data()
+    jax.block_until_ready(fn(data, sim._dyn, carry, keys, ridx)[0][0])
     dyn2 = {
         "distances": sim._dyn["distances"] * 1.5,
         "hetero": sim._dyn["hetero"] + 0.25,
         "eps": sim._dyn["eps"] * 0.5,
     }
-    jax.block_until_ready(fn(dyn2, carry, keys, ridx)[0][0])
+    jax.block_until_ready(fn(data, dyn2, carry, keys, ridx)[0][0])
     assert fn._cache_size() == 1, "dyn leaves retraced the scan"
 
 

@@ -72,23 +72,27 @@ def _toy_fleet(u=6, n_max=4):
     rng = np.random.default_rng(1)
     x = rng.normal(size=(u, n_max, 2, 2, 1)).astype(np.float32)
     n = rng.integers(1, n_max + 1, u).astype(np.int64)
-    return Fleet(
-        x=jnp.asarray(x),
+    rows = np.zeros((u, 128), np.float32)  # rows padded to 128 lanes
+    rows[:, : n_max * 4] = x.reshape(u, -1)
+    return x, Fleet(
+        x=jnp.asarray(rows),
         y=jnp.asarray(rng.integers(0, 3, (u, n_max)), jnp.int32),
         n_samples=jnp.asarray(n, jnp.int32),
         d_sizes=n,
+        sample_shape=(2, 2, 1),
     )
 
 
 def test_gather_active_picks_scheduled_rows():
-    fleet = _toy_fleet()
+    x, fleet = _toy_fleet()
     slots = jnp.asarray([4, 1, -1], jnp.int32)
     x_s, y_s, n_s = gather_active(fleet, slots)
-    assert x_s.shape == (3,) + fleet.x.shape[1:]
-    np.testing.assert_array_equal(np.asarray(x_s[0]), np.asarray(fleet.x[4]))
+    # flat client rows come back in the sample shape
+    assert x_s.shape == (3,) + x.shape[1:]
+    np.testing.assert_array_equal(np.asarray(x_s[0]), x[4])
     np.testing.assert_array_equal(np.asarray(y_s[1]), np.asarray(fleet.y[1]))
     # padding slots clip to client 0 (dead weight, masked downstream)
-    np.testing.assert_array_equal(np.asarray(x_s[2]), np.asarray(fleet.x[0]))
+    np.testing.assert_array_equal(np.asarray(x_s[2]), x[0])
     assert int(n_s[2]) == int(fleet.n_samples[0])
 
 

@@ -101,12 +101,13 @@ def test_zero_retrace_across_fault_vectors():
     fn = sim._scan_fn(False)
     keys, ridx = sim._scan_xs(2)
     carry = sim._init_carry()
-    jax.block_until_ready(fn(sim._dyn, carry, keys, ridx)[0][0])
+    data = sim.data()
+    jax.block_until_ready(fn(data, sim._dyn, carry, keys, ridx)[0][0])
     dyn2 = dict(sim._dyn)
     dyn2["faults"] = jnp.asarray(
         FaultSpec(outage_p=0.5, fade_p=0.3, fade_db=20.0,
                   corrupt_p=0.2, nan_p=0.1).dyn_vector())
-    jax.block_until_ready(fn(dyn2, carry, keys, ridx)[0][0])
+    jax.block_until_ready(fn(data, dyn2, carry, keys, ridx)[0][0])
     assert fn._cache_size() == 1, "fault vector retraced the scan"
 
 
@@ -233,7 +234,7 @@ def test_full_burst_freezes_model_bitwise():
     fn = sim._scan_fn(False)
     keys, ridx = sim._scan_xs(3)
     carry0 = sim._init_carry()
-    final_carry, _ = fn(sim._dyn, carry0, keys, ridx)
+    final_carry, _ = fn(sim.data(), sim._dyn, carry0, keys, ridx)
     np.testing.assert_array_equal(
         np.asarray(final_carry[0]), np.asarray(carry0[0]))
     res = sim.run_compiled(3)
