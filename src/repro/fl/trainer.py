@@ -21,7 +21,7 @@ import numpy as np
 from repro.core import quantization
 from repro.core.genetic import Decision, RoundContext, SystemParams
 from repro.fl.client import FLClient
-from repro.obs.profile import annotate as _annotate
+from repro.obs.profile import span
 from repro.wireless.channel import ChannelModel
 
 Pytree = Any
@@ -136,7 +136,7 @@ class FLExperiment:
         acc, loss = self.eval_fn(self.params)
         for n in range(n_rounds):
             ctx = self._context()
-            with _annotate("fl_decide"):
+            with span("fl_decide"):
                 dec = self.policy.decide(ctx)
             v_assigned = np.zeros(len(self.clients))
             for c, cid in enumerate(dec.assign):
@@ -147,7 +147,7 @@ class FLExperiment:
             weights = []
             d_n = float(np.sum(dec.a * self.d_sizes))
             payload = 0.0
-            with _annotate("fl_local_quant"):
+            with span("fl_local_quant"):
                 for i, client in enumerate(self.clients):
                     if not dec.a[i]:
                         continue
@@ -169,7 +169,7 @@ class FLExperiment:
                     payload += quantization.payload_bits(self.z, q_i)
 
             if uploads:
-                with _annotate("fl_aggregate"):
+                with span("fl_aggregate"):
                     new = jax.tree_util.tree_map(
                         lambda *leaves: sum(
                             w * l for w, l in zip(weights, leaves)

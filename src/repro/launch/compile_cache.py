@@ -8,6 +8,12 @@ is set. Otherwise the cache goes to the fixed ``<repo>/.jax_cache``
 name, so a later run in the same checkout finds what an earlier one
 compiled. Child processes started afterwards inherit the same directory
 through the environment variable.
+
+Either way the cache key includes the programs' metadata: the named
+scopes (``repro.obs.profile.scope``) live there, and a key blind to them
+would serve an executable compiled from other source, whose ``op_name``
+metadata names other scopes, so a profile of the loaded program would
+attribute its device time to the wrong layers.
 """
 from __future__ import annotations
 
@@ -22,6 +28,7 @@ REPO_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
 def enable_compile_cache() -> str:
     """Turn the persistent cache on; returns the directory in use."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     from_env = os.environ.get(ENV_VAR)
     if from_env:
         return from_env

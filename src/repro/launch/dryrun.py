@@ -46,7 +46,6 @@ import argparse
 import json
 import re
 import sys
-import time
 import traceback
 
 
@@ -123,6 +122,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool, fl_round: bool,
     from repro.launch.mesh import make_production_mesh, mesh_label
     from repro.launch import steps
     from repro.models.config import INPUT_SHAPES
+    from repro.obs.profile import span
     from repro.optim import adamw
 
     cfg = get_config(arch)
@@ -134,28 +134,26 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool, fl_round: bool,
     mesh = make_production_mesh(multi_pod=multi_pod, shape=mesh_shape)
     n_chips = mesh.devices.size
 
-    t0 = time.time()
-    if fl_round:
-        if mesh.shape.get("pod", 1) < 2:
-            raise ValueError("--fl-round needs a pod axis >= 2 (clients = pods)")
-        lowered = steps.lower_fl_round(cfg, mesh, shape)
-        step_kind = "fl_round"
-    elif shape.kind == "train":
-        lowered = steps.lower_train_step(
-            cfg, mesh, shape, adamw(3e-4), causal_skip=causal_skip
-        )
-        step_kind = "train"
-    elif shape.kind == "prefill":
-        lowered = steps.lower_prefill_step(cfg, mesh, shape)
-        step_kind = "prefill"
-    else:
-        lowered = steps.lower_decode_step(cfg, mesh, shape)
-        step_kind = "decode"
-    t_lower = time.time() - t0
-
-    t0 = time.time()
-    compiled = lowered.compile()
-    t_compile = time.time() - t0
+    with span("dryrun.lower", arch=arch, shape=shape_name) as t_lower:
+        if fl_round:
+            if mesh.shape.get("pod", 1) < 2:
+                raise ValueError(
+                    "--fl-round needs a pod axis >= 2 (clients = pods)")
+            lowered = steps.lower_fl_round(cfg, mesh, shape)
+            step_kind = "fl_round"
+        elif shape.kind == "train":
+            lowered = steps.lower_train_step(
+                cfg, mesh, shape, adamw(3e-4), causal_skip=causal_skip
+            )
+            step_kind = "train"
+        elif shape.kind == "prefill":
+            lowered = steps.lower_prefill_step(cfg, mesh, shape)
+            step_kind = "prefill"
+        else:
+            lowered = steps.lower_decode_step(cfg, mesh, shape)
+            step_kind = "decode"
+    with span("dryrun.compile", arch=arch, shape=shape_name) as t_compile:
+        compiled = lowered.compile()
 
     from repro.dist.hlo_analysis import (
         full_length_intermediates, loop_summary, weighted_collectives,
@@ -246,8 +244,8 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool, fl_round: bool,
         "n_chips": int(n_chips),
         "ok": True,
         **gates,
-        "lower_s": round(t_lower, 2),
-        "compile_s": round(t_compile, 2),
+        "lower_s": round(t_lower.seconds, 2),
+        "compile_s": round(t_compile.seconds, 2),
         "hlo_flops_per_device_raw": flops,
         "hlo_bytes_per_device_raw": bytes_acc,
         **ana,
@@ -294,6 +292,7 @@ def run_wire_ratio(arch: str, shape_name: str, downlink: str = "off") -> dict:
     from repro.launch.mesh import make_production_mesh, mesh_label
     from repro.models import abstract_params
     from repro.models.config import INPUT_SHAPES
+    from repro.obs.profile import span
 
     cfg = get_config(arch)
     shape = INPUT_SHAPES[shape_name]
@@ -305,19 +304,19 @@ def run_wire_ratio(arch: str, shape_name: str, downlink: str = "off") -> dict:
         "step": "fl_round_wire_ratio", "downlink": downlink, "ok": True,
     }
     for packed in (False, True):
-        t0 = time.time()
-        hlo = steps.lower_fl_round(
-            cfg, mesh, shape, wire_packed=packed, downlink=downlink
-        ).compile().as_text()
-        r = inter_axis_bytes(hlo, pods)
-        split = wire_payload_split(r)
-        mode = "packed" if packed else "fp32"
-        rec[f"{mode}_inter_bytes"] = r["inter_bytes"]
-        rec[f"{mode}_unattributed_bytes"] = r["unattributed_bytes"]
-        rec[f"{mode}_inter_by_kind"] = r["inter_by_kind"]
-        rec[f"{mode}_inter_wire_bytes"] = split["wire_bytes"]
-        rec[f"{mode}_inter_dense_bytes"] = split["dense_bytes"]
-        rec[f"{mode}_wall_s"] = round(time.time() - t0, 1)
+        with span("dryrun.wire_ratio", packed=packed) as t:
+            hlo = steps.lower_fl_round(
+                cfg, mesh, shape, wire_packed=packed, downlink=downlink
+            ).compile().as_text()
+            r = inter_axis_bytes(hlo, pods)
+            split = wire_payload_split(r)
+            mode = "packed" if packed else "fp32"
+            rec[f"{mode}_inter_bytes"] = r["inter_bytes"]
+            rec[f"{mode}_unattributed_bytes"] = r["unattributed_bytes"]
+            rec[f"{mode}_inter_by_kind"] = r["inter_by_kind"]
+            rec[f"{mode}_inter_wire_bytes"] = split["wire_bytes"]
+            rec[f"{mode}_inter_dense_bytes"] = split["dense_bytes"]
+            rec[f"{mode}_wall_s"] = round(t.seconds, 1)
     # attribution must not silently degrade into the unattributed bucket
     assert rec["fp32_inter_bytes"] > 0 and rec["packed_inter_bytes"] > 0, rec
     assert max(

@@ -6,7 +6,6 @@
 from __future__ import annotations
 
 import argparse
-import time
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +29,7 @@ def main() -> None:
     from repro.launch.mesh import make_host_mesh
     from repro.models import decode_step, init_params
     from repro.models.decode import encode, init_cache, prefill
+    from repro.obs.profile import span
 
     cfg = get_reduced(args.arch)
     # serve-mode plan: tensor parallelism only, params replicated over the
@@ -69,12 +69,12 @@ def main() -> None:
 
     step = jax.jit(_step, donate_argnums=(1, 3))
     out_buf = jnp.zeros((b, args.new_tokens + 1), jnp.int32).at[:, 0].set(tokens)
-    t0 = time.time()
-    for i in range(args.new_tokens):
-        cache, tokens, out_buf = step(params, cache, tokens, out_buf,
-                                      jnp.int32(i + 1))
-    jax.block_until_ready(tokens)
-    dt = time.time() - t0
+    with span("serve.decode", tokens=args.new_tokens) as decode:
+        for i in range(args.new_tokens):
+            cache, tokens, out_buf = step(params, cache, tokens, out_buf,
+                                          jnp.int32(i + 1))
+        jax.block_until_ready(tokens)
+    dt = decode.seconds
     print(f"{args.new_tokens} tokens x {b} requests in {dt:.2f}s "
           f"({args.new_tokens * b / dt:.1f} tok/s)")
     gen = np.asarray(out_buf)

@@ -12,7 +12,6 @@ clients = pods on the multi-pod mesh).
 from __future__ import annotations
 
 import argparse
-import time
 
 import jax
 import jax.numpy as jnp
@@ -54,7 +53,7 @@ def main() -> None:
     from repro.launch.mesh import make_host_mesh, make_production_mesh
     from repro.launch.steps import make_train_step
     from repro.models import init_params
-    from repro.obs import default_ledger, maybe_trace
+    from repro.obs import default_ledger, maybe_trace, span
     from repro.optim import adamw
 
     ledger = default_ledger(args.ledger)
@@ -122,50 +121,52 @@ def main() -> None:
         if args.fl_interval and (i + 1) % args.fl_interval == 0:
             key, _, _ = jax.random.split(key, 3)
     metrics = None
-    t0 = time.time()
-    for i in range(start_step, args.steps):
-        toks = jnp.asarray(rng.integers(0, cfg.vocab, (b, s)), jnp.int32)
-        batch = {"tokens": toks, "labels": toks, "mask": jnp.ones((b, s))}
-        if cfg.family == "encdec":
-            batch["src_embeds"] = jnp.asarray(
-                rng.normal(size=(b, s, cfg.d_model)), jnp.float32)
-        if cfg.family == "vlm":
-            batch["vis_embeds"] = jnp.asarray(
-                rng.normal(size=(b, cfg.n_vis_tokens, cfg.d_model)), jnp.float32)
-        params, opt_state, metrics = step(params, opt_state, batch)
-        if i == start_step:
-            jax.block_until_ready(metrics["loss"])
-            ledger.timing("first_step", time.time() - t0,
-                          entry="launch.train", note="includes compile")
-            if args.xprof:  # steady state only: compile is done
-                prof.enter_context(maybe_trace(args.xprof))
-        if i % 10 == 0 or i == args.steps - 1:
-            print(f"step {i:4d} loss {float(metrics['loss']):.4f} "
-                  f"gnorm {float(metrics['grad_norm']):.3f} "
-                  f"({(time.time()-t0)/(i-start_step+1):.2f}s/step)",
-                  flush=True)
-        if args.fl_interval and (i + 1) % args.fl_interval == 0:
-            # paper eq. 2 on 2 virtual clients: quantize + weighted-average
-            key, k1, k2 = jax.random.split(key, 3)
-            q1, t1 = quantize_pytree(k1, params, args.fl_q)
-            q2, t2 = quantize_pytree(k2, params, args.fl_q)
-            params = jax.tree_util.tree_map(
-                lambda a, c: (0.5 * a.astype(jnp.float32)
-                              + 0.5 * c.astype(jnp.float32)).astype(a.dtype),
-                q1, q2,
-            )
-            print(f"  fl sync @ step {i+1}: q={args.fl_q} "
-                  f"theta_max={float(t1):.3f}", flush=True)
-        if args.ckpt_dir and (i + 1) % args.ckpt_every == 0:
-            path = save_checkpoint(args.ckpt_dir, i + 1, params,
-                                   extra={"loss": float(metrics["loss"])})
-            print(f"  saved {path}", flush=True)
+    with span("train.loop", steps=args.steps) as loop:
+        for i in range(start_step, args.steps):
+            toks = jnp.asarray(rng.integers(0, cfg.vocab, (b, s)), jnp.int32)
+            batch = {"tokens": toks, "labels": toks, "mask": jnp.ones((b, s))}
+            if cfg.family == "encdec":
+                batch["src_embeds"] = jnp.asarray(
+                    rng.normal(size=(b, s, cfg.d_model)), jnp.float32)
+            if cfg.family == "vlm":
+                batch["vis_embeds"] = jnp.asarray(
+                    rng.normal(size=(b, cfg.n_vis_tokens, cfg.d_model)),
+                    jnp.float32)
+            params, opt_state, metrics = step(params, opt_state, batch)
+            if i == start_step:
+                jax.block_until_ready(metrics["loss"])
+                ledger.timing("first_step", loop.seconds,
+                              entry="launch.train", note="includes compile")
+                if args.xprof:  # steady state only: compile is done
+                    prof.enter_context(maybe_trace(args.xprof))
+            if i % 10 == 0 or i == args.steps - 1:
+                print(f"step {i:4d} loss {float(metrics['loss']):.4f} "
+                      f"gnorm {float(metrics['grad_norm']):.3f} "
+                      f"({loop.seconds/(i-start_step+1):.2f}s/step)",
+                      flush=True)
+            if args.fl_interval and (i + 1) % args.fl_interval == 0:
+                # paper eq. 2 on 2 virtual clients: quantize + weighted-average
+                key, k1, k2 = jax.random.split(key, 3)
+                q1, t1 = quantize_pytree(k1, params, args.fl_q)
+                q2, t2 = quantize_pytree(k2, params, args.fl_q)
+                params = jax.tree_util.tree_map(
+                    lambda a, c: (0.5 * a.astype(jnp.float32)
+                                  + 0.5 * c.astype(jnp.float32)
+                                  ).astype(a.dtype),
+                    q1, q2,
+                )
+                print(f"  fl sync @ step {i+1}: q={args.fl_q} "
+                      f"theta_max={float(t1):.3f}", flush=True)
+            if args.ckpt_dir and (i + 1) % args.ckpt_every == 0:
+                path = save_checkpoint(args.ckpt_dir, i + 1, params,
+                                       extra={"loss": float(metrics["loss"])})
+                print(f"  saved {path}", flush=True)
     prof.close()
     if metrics is None:
         print(f"nothing to do: resumed step {start_step} >= --steps "
               f"{args.steps}", flush=True)
         return
-    ledger.timing("train_loop", time.time() - t0, entry="launch.train",
+    ledger.timing("train_loop", loop.seconds, entry="launch.train",
                   steps=args.steps,
                   final_loss=float(metrics["loss"]))
 

@@ -49,6 +49,8 @@ import subprocess
 import time
 from typing import Any, Callable, Iterator, Optional
 
+from repro.obs.profile import span
+
 LEDGER_SCHEMA_VERSION = 1
 REPRO_LEDGER_ENV = "REPRO_LEDGER"
 
@@ -229,27 +231,21 @@ def default_ledger(path: Optional[str] = None) -> Ledger:
     return Ledger(path or os.environ.get(REPRO_LEDGER_ENV) or None)
 
 
-class PhaseTiming:
-    """What ``timed_phase`` yields; ``seconds`` is set on exit."""
-
-    def __init__(self, name: str):
-        self.name = name
-        self.seconds: float = 0.0
-
-
 @contextlib.contextmanager
 def timed_phase(
     name: str,
     ledger: Optional[Ledger] = None,
     warmup: Optional[Callable[[], Any]] = None,
     **meta: Any,
-) -> Iterator[PhaseTiming]:
+) -> Iterator[span]:
     """The one timing block the benchmark drivers share.
 
     Runs ``warmup`` (jit pre-compiles etc.) BEFORE the clock starts, so
-    the measured region never includes one-time costs; yields a
-    :class:`PhaseTiming` whose ``.seconds`` is valid after the block; and
-    emits a ledger ``timing`` event when a ledger is given.
+    the measured region never includes one-time costs; times the block
+    as a :class:`repro.obs.profile.span` named ``name`` (so the phase also
+    appears in profiler traces and recordings) and yields it, its
+    ``.seconds`` the phase's duration after the block; and emits a ledger
+    ``timing`` event when a ledger is given.
 
         with timed_phase("run", ledger, warmup=warm) as t:
             do_work()
@@ -257,11 +253,10 @@ def timed_phase(
     """
     if warmup is not None:
         warmup()
-    t = PhaseTiming(name)
-    t0 = time.perf_counter()
+    t = span(name, **meta)
     try:
-        yield t
+        with t:
+            yield t
     finally:
-        t.seconds = time.perf_counter() - t0
         if ledger is not None:
             ledger.timing(name, t.seconds, **meta)

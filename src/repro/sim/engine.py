@@ -42,7 +42,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Any, Optional
 
 import jax
@@ -57,6 +56,7 @@ from repro.core.genetic import GAConfig, RoundContext, SystemParams
 from repro.obs import ledger as obs_ledger
 from repro.obs import metrics as obs_metrics
 from repro.obs.metrics import MetricsConfig
+from repro.obs.profile import scope, span
 from repro.data.synthetic import (
     SyntheticImageTask, gaussian_sizes, hetero_kl, make_federated_datasets,
     make_test_set,
@@ -414,6 +414,7 @@ class FleetSim:
         if self.faults.enabled:
             self._dyn["faults"] = jnp.asarray(self.faults.dyn_vector())
         self._compiled: dict = {}
+        self._runs = 0  # experiments run so far (the host spans' run id)
         # set by shard_clients: the mesh, and the mesh axes the fleet's
         # client rows are sharded over (None: not sharded)
         self._mesh = None
@@ -488,40 +489,17 @@ class FleetSim:
                    / (4.0 * levels**2)).astype(jnp.float32)
         return bcast, dl_next
 
-    def _round_body(self, data, dyn, carry, xs, with_eval: bool):
-        flat, g_sq, sigma_sq, theta_max, lam1, lam2 = carry[:6]
-        tail = 6
-        dl_prev = None
-        out_state = None
-        if self.downlink.enabled:
-            # 7th carry slot: last round's realized downlink bound term
-            dl_prev = carry[tail]
-            tail += 1
-        if self.faults.enabled:
-            # trailing carry slot: the (U,) Markov outage state (1.0 = the
-            # client was in outage last round), see scenario.FaultSpec
-            out_state = carry[tail]
-        key, ridx = xs
-        k_ch, k_batch, k_quant = jax.random.split(key, 3)
+    def _decide(self, key, ridx, rates, fleet, dyn, g_sq, sigma_sq,
+                theta_max, lam1, lam2, dl_prev):
+        """The round's decision under ``policy_mode``: returns ``(dec,
+        ga_stats, g_n, s_n, d_sizes)``, the normalised estimators and the
+        client sizes the rest of the round reuses."""
         sysp, z = self.sysp, self.z
-        if self.faults.enabled:
-            fv = dyn["faults"]
-            k_out, k_fade, k_corr, k_burst = fault_keys(key)
-            down_u = draw_outage(k_out, out_state, fv)
-            fade_hit_u, fade_mult_u = draw_fade(
-                k_fade, self.fleet.n_clients, fv)
-            new_out_state = down_u.astype(jnp.float32)
-
-        rates = sim_channel.draw_rates(
-            k_ch, self.channel.params, dyn["distances"],
-            self.channel.association,
-        )
-        g_n = g_sq / jnp.maximum(jnp.mean(g_sq), 1e-12)
-        s_n = sigma_sq / jnp.maximum(jnp.mean(sigma_sq), 1e-12)
-        fleet = self._traced_fleet(data)
-        d_sizes = fleet.n_samples.astype(jnp.float32)
         mode = self.policy_mode
         mcfg = self.metrics_cfg
+        g_n = g_sq / jnp.maximum(jnp.mean(g_sq), 1e-12)
+        s_n = sigma_sq / jnp.maximum(jnp.mean(sigma_sq), 1e-12)
+        d_sizes = fleet.n_samples.astype(jnp.float32)
         # static gate: GA fitness taps only exist in the trace when asked
         ga_stats = None
         tap_ga = mcfg.enabled and mcfg.ga_fitness
@@ -583,143 +561,196 @@ class FleetSim:
                 self.v_weight, q_cap=self.q_cap, hetero=dyn["hetero"],
                 dl_term=dl_prev,
             )
+        return dec, ga_stats, g_n, s_n, d_sizes
+
+    def _round_body(self, data, dyn, carry, xs, with_eval: bool):
+        flat, g_sq, sigma_sq, theta_max, lam1, lam2 = carry[:6]
+        tail = 6
+        dl_prev = None
+        out_state = None
+        if self.downlink.enabled:
+            # 7th carry slot: last round's realized downlink bound term
+            dl_prev = carry[tail]
+            tail += 1
+        if self.faults.enabled:
+            # trailing carry slot: the (U,) Markov outage state (1.0 = the
+            # client was in outage last round), see scenario.FaultSpec
+            out_state = carry[tail]
+        key, ridx = xs
+        sysp, z = self.sysp, self.z
+        # Each stage of the round runs under one of obs.profile's
+        # ROUND_SCOPES (HLO metadata only: the lowered program is the same)
+        with scope("channel_draw"):
+            k_ch, k_batch, k_quant = jax.random.split(key, 3)
+            if self.faults.enabled:
+                fv = dyn["faults"]
+                k_out, k_fade, k_corr, k_burst = fault_keys(key)
+                down_u = draw_outage(k_out, out_state, fv)
+                fade_hit_u, fade_mult_u = draw_fade(
+                    k_fade, self.fleet.n_clients, fv)
+                new_out_state = down_u.astype(jnp.float32)
+            rates = sim_channel.draw_rates(
+                k_ch, self.channel.params, dyn["distances"],
+                self.channel.association,
+            )
+        fleet = self._traced_fleet(data)
+        mode = self.policy_mode
+        mcfg = self.metrics_cfg
+        with scope("decision"):
+            dec, ga_stats, g_n, s_n, d_sizes = self._decide(
+                key, ridx, rates, fleet, dyn, g_sq, sigma_sq, theta_max,
+                lam1, lam2, dl_prev)
         # ---- active-set compaction: O(U) work ends with the decision.
         # Everything below lives on the fixed S = min(U, C) slot axis.
         u = self.fleet.n_clients
-        slots = dec.slots                                  # (S,) ids, -1 pad
-        sm = slots >= 0
-        cid = jnp.maximum(slots, 0)
-
-        params = self.unravel(flat)
-        x_s, y_s, n_s = gather_active(fleet, slots, mesh=self._mesh,
-                                      client_axes=self._client_axes)
+        with scope("slot_gather"):
+            slots = dec.slots                              # (S,) ids, -1 pad
+            sm = slots >= 0
+            cid = jnp.maximum(slots, 0)
+            params = self.unravel(flat)
+            x_s, y_s, n_s = gather_active(fleet, slots, mesh=self._mesh,
+                                          client_axes=self._client_axes)
         stacked, g_obs, s_obs = fleet_local_sgd(
             self.loss_fn, sysp.tau, self.batch_size, params,
             x_s, y_s, n_s, self.lr, k_batch,
         )
-        flat_s = jax.vmap(lambda p: ravel_pytree(p)[0])(stacked)  # (S, Z)
-        if self.faults.enabled:
-            flat_s = inject_burst(k_burst, slots, flat_s, fv)
+        with scope("quantize_wire"):
+            flat_s = jax.vmap(lambda p: ravel_pytree(p)[0])(stacked)  # (S, Z)
+            if self.faults.enabled:
+                flat_s = inject_burst(k_burst, slots, flat_s, fv)
 
-        q_slot = jnp.take(dec.q, cid) * sm.astype(jnp.int32)
-        idx, signs, theta = _quantize_wire(
-            k_quant, flat_s, q_slot, self.q_cap, self._zpad
-        )
-        d_slot = jnp.take(d_sizes, cid) * sm.astype(jnp.float32)
-        if self.faults.enabled:
-            # wire corruption, then the graceful-degradation screen: a
-            # screened slot's weight AND payload are zeroed (theta = NaN
-            # with w = 0 would still poison the aggregate coefficient) and
-            # the eq.-2 weights renormalize over the survivors.
-            idx, signs = corrupt_planes(k_corr, idx, signs, fv)
-            v_slot = jnp.take(dec.v_assigned, cid) * sm.astype(jnp.float32)
-            f_slot = jnp.take(dec.f, cid) * sm.astype(jnp.float32)
-            ok, n_dropped, n_timeout_real, n_screened = screen_slots(
-                slots, q_slot, d_slot, v_slot, f_slot, theta, idx, signs,
-                down_u, fade_mult_u, fade_hit_u, sysp, z,
+            q_slot = jnp.take(dec.q, cid) * sm.astype(jnp.int32)
+            idx, signs, theta = _quantize_wire(
+                k_quant, flat_s, q_slot, self.q_cap, self._zpad
             )
-            theta = jnp.where(ok, theta, 0.0)
-            flat_s = jnp.where(ok[:, None], flat_s, 0.0)
-            d_eff = d_slot * ok.astype(jnp.float32)
-        else:
-            d_eff = d_slot
-        d_n = jnp.sum(d_eff)
-        w_slot = d_eff / jnp.maximum(d_n, 1e-12)           # eq. 2 weights
+            d_slot = jnp.take(d_sizes, cid) * sm.astype(jnp.float32)
+            if self.faults.enabled:
+                # wire corruption, then the graceful-degradation screen: a
+                # screened slot's weight AND payload are zeroed (theta = NaN
+                # with w = 0 would still poison the aggregate coefficient)
+                # and the eq.-2 weights renormalize over the survivors.
+                idx, signs = corrupt_planes(k_corr, idx, signs, fv)
+                v_slot = jnp.take(dec.v_assigned, cid) * sm.astype(
+                    jnp.float32)
+                f_slot = jnp.take(dec.f, cid) * sm.astype(jnp.float32)
+                ok, n_dropped, n_timeout_real, n_screened = screen_slots(
+                    slots, q_slot, d_slot, v_slot, f_slot, theta, idx, signs,
+                    down_u, fade_mult_u, fade_hit_u, sysp, z,
+                )
+                theta = jnp.where(ok, theta, 0.0)
+                flat_s = jnp.where(ok[:, None], flat_s, 0.0)
+                d_eff = d_slot * ok.astype(jnp.float32)
+            else:
+                d_eff = d_slot
+            d_n = jnp.sum(d_eff)
+            w_slot = d_eff / jnp.maximum(d_n, 1e-12)       # eq. 2 weights
         agg = self._aggregate(idx, signs, theta, w_slot, q_slot)
-        new_flat = jnp.where(d_n > 0, agg[: self.z], flat)
+        with scope("server_update"):
+            new_flat = jnp.where(d_n > 0, agg[: self.z], flat)
         if self.downlink.enabled:
             # the carried model becomes what the CLIENTS reconstruct from
             # the quantized broadcast — next round's local SGD (and the
             # eval below) start from it, like the real wire would
             exact_flat = new_flat
-            new_flat, dl_next = self._downlink_apply(key, new_flat, flat)
+            with scope("downlink"):
+                new_flat, dl_next = self._downlink_apply(key, new_flat, flat)
 
-        if self.faults.enabled:
-            # graceful degradation, server side: only delivered slots feed
-            # the G^2 / sigma^2 / theta estimators, and the Lyapunov queues
-            # get the REALIZED eq.-20/21 terms — a scheduled-but-failed
-            # client re-enters the scheduling-exclusion sum exactly like an
-            # unscheduled one, so the controller adapts q and scheduling to
-            # the observed outage rate. Same hetero / downlink routing as
-            # the decision (the baselines stay queue-blind there too).
-            a_real_u = scatter_slots(slots, ok.astype(jnp.float32), u)
-            use_ctx = mode in ("greedy", "compiled-ga")
-            dt_real, qt_real = fast_policy.realized_terms(
-                a_real_u, d_sizes, g_n, s_n, theta_max, dec.q, sysp, z,
-                hetero=dyn["hetero"] if use_ctx else None,
-                dl_term=dl_prev if use_ctx else None,
-            )
-            g_sq = ema_update(
-                g_sq, scatter_slots(slots, jnp.where(ok, g_obs, 0.0), u),
-                a_real_u)
-            sigma_sq = ema_update(
-                sigma_sq, scatter_slots(slots, jnp.where(ok, s_obs, 0.0), u),
-                a_real_u, floor=1e-8)
-            theta_max = jnp.where(a_real_u > 0,
-                                  scatter_slots(slots, theta, u), theta_max)
-            lam1 = jnp.maximum(lam1 + dt_real - dyn["eps"][0], 0.0)
-            lam2 = jnp.maximum(lam2 + qt_real - dyn["eps"][1], 0.0)
-        else:
-            g_sq = ema_update(g_sq, scatter_slots(slots, g_obs, u), dec.a)
-            sigma_sq = ema_update(sigma_sq, scatter_slots(slots, s_obs, u),
-                                  dec.a, floor=1e-8)
-            theta_max = jnp.where(dec.a > 0, scatter_slots(slots, theta, u),
-                                  theta_max)
-            lam1 = jnp.maximum(lam1 + dec.data_term - dyn["eps"][0], 0.0)
-            lam2 = jnp.maximum(lam2 + dec.quant_term - dyn["eps"][1], 0.0)
+        with scope("server_update"):
+            if self.faults.enabled:
+                # graceful degradation, server side: only delivered slots
+                # feed the G^2 / sigma^2 / theta estimators, and the
+                # Lyapunov queues get the REALIZED eq.-20/21 terms — a
+                # scheduled-but-failed client re-enters the
+                # scheduling-exclusion sum exactly like an unscheduled one,
+                # so the controller adapts q and scheduling to the observed
+                # outage rate. Same hetero / downlink routing as the
+                # decision (the baselines stay queue-blind there too).
+                a_real_u = scatter_slots(slots, ok.astype(jnp.float32), u)
+                use_ctx = mode in ("greedy", "compiled-ga")
+                dt_real, qt_real = fast_policy.realized_terms(
+                    a_real_u, d_sizes, g_n, s_n, theta_max, dec.q, sysp, z,
+                    hetero=dyn["hetero"] if use_ctx else None,
+                    dl_term=dl_prev if use_ctx else None,
+                )
+                g_sq = ema_update(
+                    g_sq, scatter_slots(slots, jnp.where(ok, g_obs, 0.0), u),
+                    a_real_u)
+                sigma_sq = ema_update(
+                    sigma_sq,
+                    scatter_slots(slots, jnp.where(ok, s_obs, 0.0), u),
+                    a_real_u, floor=1e-8)
+                theta_max = jnp.where(a_real_u > 0,
+                                      scatter_slots(slots, theta, u),
+                                      theta_max)
+                lam1 = jnp.maximum(lam1 + dt_real - dyn["eps"][0], 0.0)
+                lam2 = jnp.maximum(lam2 + qt_real - dyn["eps"][1], 0.0)
+            else:
+                g_sq = ema_update(g_sq, scatter_slots(slots, g_obs, u), dec.a)
+                sigma_sq = ema_update(sigma_sq,
+                                      scatter_slots(slots, s_obs, u),
+                                      dec.a, floor=1e-8)
+                theta_max = jnp.where(dec.a > 0,
+                                      scatter_slots(slots, theta, u),
+                                      theta_max)
+                lam1 = jnp.maximum(lam1 + dec.data_term - dyn["eps"][0], 0.0)
+                lam2 = jnp.maximum(lam2 + dec.quant_term - dyn["eps"][1],
+                                   0.0)
 
         if with_eval:
-            acc, loss = self.eval_fn(new_flat, data["test"])
+            with scope("eval"):
+                acc, loss = self.eval_fn(new_flat, data["test"])
         else:
             acc, loss = jnp.float32(0.0), jnp.float32(0.0)
-        out = {
-            "energy": jnp.sum(dec.energy),
-            "accuracy": acc,
-            "loss": loss,
-            "n_scheduled": jnp.sum(dec.a),
-            "q_levels": dec.q,
-            "latency": jnp.max(dec.latency),
-            "payload_bits": dec.payload_bits,
-            "rates": dec.v_assigned,
-            "lambda1": lam1,
-            "lambda2": lam2,
-        }
+        with scope("server_update"):
+            out = {
+                "energy": jnp.sum(dec.energy),
+                "accuracy": acc,
+                "loss": loss,
+                "n_scheduled": jnp.sum(dec.a),
+                "q_levels": dec.q,
+                "latency": jnp.max(dec.latency),
+                "payload_bits": dec.payload_bits,
+                "rates": dec.v_assigned,
+                "lambda1": lam1,
+                "lambda2": lam2,
+            }
         if mcfg.enabled:
             # telemetry taps ride the scan as extra ys — every op here is
             # behind the static gate, so telemetry=off traces the exact
             # pre-telemetry program (HLO identity, tests/test_obs.py)
-            rm = obs_metrics.decision_metrics(
-                dec.a, dec.q, dec.q_cont, dec.f, dec.energy, d_sizes,
-                dec.data_term, dec.quant_term, sysp,
-            )
-            if mcfg.quant_mse:
-                # realized wire error vs the unquantized eq.-2 aggregate
-                exact = jnp.einsum("s,sz->z", w_slot, flat_s)
-                mse = jnp.sum((agg[: self.z] - exact) ** 2) / self.z
-                rm = dataclasses.replace(
-                    rm, quant_mse=jnp.where(d_n > 0, mse,
-                                            jnp.float32(float("nan"))),
+            with scope("telemetry"):
+                rm = obs_metrics.decision_metrics(
+                    dec.a, dec.q, dec.q_cont, dec.f, dec.energy, d_sizes,
+                    dec.data_term, dec.quant_term, sysp,
                 )
-            if ga_stats is not None:
-                rm = dataclasses.replace(
-                    rm, ga_best=ga_stats["ga_best"],
-                    ga_median=ga_stats["ga_median"],
-                )
-            if self.downlink.enabled:
-                # broadcast payload (analytic eq.-5 format) + realized
-                # broadcast error vs the exact aggregate
-                dl_bits = jnp.float32(core_quant.payload_bits(
-                    self.z, self.downlink.q_bits))
-                rm = dataclasses.replace(rm, dl_payload_bits=dl_bits)
                 if mcfg.quant_mse:
-                    dl_mse = jnp.sum((new_flat - exact_flat) ** 2) / self.z
-                    rm = dataclasses.replace(rm, dl_mse=dl_mse)
-            if self.faults.enabled:
-                rm = dataclasses.replace(
-                    rm, n_dropped=n_dropped, n_screened=n_screened,
-                    n_timeout_real=n_timeout_real,
-                )
-            out["metrics"] = rm
+                    # realized wire error vs the unquantized eq.-2 aggregate
+                    exact = jnp.einsum("s,sz->z", w_slot, flat_s)
+                    mse = jnp.sum((agg[: self.z] - exact) ** 2) / self.z
+                    rm = dataclasses.replace(
+                        rm, quant_mse=jnp.where(d_n > 0, mse,
+                                                jnp.float32(float("nan"))),
+                    )
+                if ga_stats is not None:
+                    rm = dataclasses.replace(
+                        rm, ga_best=ga_stats["ga_best"],
+                        ga_median=ga_stats["ga_median"],
+                    )
+                if self.downlink.enabled:
+                    # broadcast payload (analytic eq.-5 format) + realized
+                    # broadcast error vs the exact aggregate
+                    dl_bits = jnp.float32(core_quant.payload_bits(
+                        self.z, self.downlink.q_bits))
+                    rm = dataclasses.replace(rm, dl_payload_bits=dl_bits)
+                    if mcfg.quant_mse:
+                        dl_mse = jnp.sum((new_flat - exact_flat) ** 2) / self.z
+                        rm = dataclasses.replace(rm, dl_mse=dl_mse)
+                if self.faults.enabled:
+                    rm = dataclasses.replace(
+                        rm, n_dropped=n_dropped, n_screened=n_screened,
+                        n_timeout_real=n_timeout_real,
+                    )
+                out["metrics"] = rm
         new_carry = (new_flat, g_sq, sigma_sq, theta_max, lam1, lam2)
         if self.downlink.enabled:
             new_carry = new_carry + (dl_next,)
@@ -857,17 +888,25 @@ class FleetSim:
                                       ckpt_dir)
         if ckpt_dir is not None:
             raise ValueError("ckpt_dir requires segment=k (segmented scan)")
-        fn = self._jitted_scan(with_eval)
-        keys, ridx = self._scan_xs(n_rounds)
-        t0 = time.perf_counter()
-        (flat, *_rest), out = fn(self.data(), self._dyn, self._init_carry(),
-                                 keys, ridx)
-        jax.block_until_ready(out["energy"])
-        run_s = time.perf_counter() - t0
+        run = self._next_run()
+        with span("sim.run", run=run) as whole:
+            with span("sim.dispatch", run=run):
+                fn = self._jitted_scan(with_eval)
+                keys, ridx = self._scan_xs(n_rounds)
+                (flat, *_rest), out = fn(self.data(), self._dyn,
+                                         self._init_carry(), keys, ridx)
+            with span("sim.device_wait", run=run):
+                jax.block_until_ready(out["energy"])
+            with span("sim.fetch", run=run):
+                res = self._result_from_out(self._np_out(out))
         self.final_flat = flat
-        res = self._result_from_out(self._np_out(out))
-        self._write_run_ledger("run_compiled", n_rounds, res, run_s)
+        self._write_run_ledger("run_compiled", n_rounds, res, whole.seconds)
         return res
+
+    def _next_run(self) -> int:
+        """The ``run`` id the host spans of the next experiment carry."""
+        self._runs += 1
+        return self._runs
 
     def _run_segments(self, n_rounds: int, with_eval: bool, segment: int,
                       ckpt_dir: Optional[str], *, start: int = 0,
@@ -880,37 +919,42 @@ class FleetSim:
         once — at most two: k and the remainder)."""
         from repro import ckpt as repro_ckpt
 
-        fn = self._jitted_scan(with_eval)
-        keys, ridx = self._scan_xs(n_rounds)
-        carry = self._init_carry() if carry is None else carry
-        parts = [] if parts is None else list(parts)
-        t0 = time.perf_counter()
-        for b in range(start, n_rounds, segment):
-            e = min(b + segment, n_rounds)
-            carry, out = fn(self.data(), self._dyn, carry, keys[b:e],
-                            ridx[b:e])
-            jax.block_until_ready(out["energy"])
-            parts.append(self._np_out(out))
-            if ckpt_dir is not None and e < n_rounds:
-                tree = {
-                    "carry": {f"c{i:02d}": np.asarray(leaf)
-                              for i, leaf in enumerate(carry)},
-                    "out": self._concat_out(parts),
-                }
-                repro_ckpt.save_checkpoint(ckpt_dir, e, tree, extra={
-                    "kind": "sim_segment", "next_round": int(e),
-                    "n_rounds": int(n_rounds), "segment": int(segment),
-                    "with_eval": bool(with_eval), "seed": self.seed,
-                    "dyn_hash": obs_ledger.pytree_hash(self._dyn),
-                    "sim_name": self.name,
-                })
-                if self.ledger.enabled:
-                    self.ledger.write("resume", step=int(e), action="save",
-                                      dir=str(ckpt_dir))
-        run_s = time.perf_counter() - t0
+        run = self._next_run()
+        with span("sim.run", run=run) as whole:
+            with span("sim.dispatch", run=run):
+                fn = self._jitted_scan(with_eval)
+                keys, ridx = self._scan_xs(n_rounds)
+                carry = self._init_carry() if carry is None else carry
+            parts = [] if parts is None else list(parts)
+            for b in range(start, n_rounds, segment):
+                e = min(b + segment, n_rounds)
+                with span("sim.dispatch", run=run):
+                    carry, out = fn(self.data(), self._dyn, carry,
+                                    keys[b:e], ridx[b:e])
+                with span("sim.device_wait", run=run):
+                    jax.block_until_ready(out["energy"])
+                with span("sim.fetch", run=run):
+                    parts.append(self._np_out(out))
+                if ckpt_dir is not None and e < n_rounds:
+                    tree = {
+                        "carry": {f"c{i:02d}": np.asarray(leaf)
+                                  for i, leaf in enumerate(carry)},
+                        "out": self._concat_out(parts),
+                    }
+                    repro_ckpt.save_checkpoint(ckpt_dir, e, tree, extra={
+                        "kind": "sim_segment", "next_round": int(e),
+                        "n_rounds": int(n_rounds), "segment": int(segment),
+                        "with_eval": bool(with_eval), "seed": self.seed,
+                        "dyn_hash": obs_ledger.pytree_hash(self._dyn),
+                        "sim_name": self.name,
+                    })
+                    if self.ledger.enabled:
+                        self.ledger.write("resume", step=int(e),
+                                          action="save", dir=str(ckpt_dir))
+            with span("sim.fetch", run=run):
+                res = self._result_from_out(self._concat_out(parts))
         self.final_flat = carry[0]
-        res = self._result_from_out(self._concat_out(parts))
-        self._write_run_ledger(entry, n_rounds, res, run_s)
+        self._write_run_ledger(entry, n_rounds, res, whole.seconds)
         return res
 
     def resume_compiled(self, ckpt_dir: str) -> SimResult:
@@ -1155,6 +1199,31 @@ class FleetSim:
         assert channel in ("sim", "host")
         if channel == "host":
             assert self.host_channel is not None, "build with a host ChannelModel"
+        with span("sim.run", run=self._next_run()) as whole:
+            records, host_metrics = self._host_rounds(policy, n_rounds,
+                                                      channel, with_eval)
+        mcfg = self.metrics_cfg
+        result = ExperimentResult(getattr(policy, "name", "host_policy"),
+                                  records)
+        if self.ledger.enabled:
+            self._ledger_header("run_host_policy", n_rounds)
+            for n, rec in enumerate(records):
+                row = dict(
+                    energy=rec.energy, accuracy=rec.accuracy, loss=rec.loss,
+                    n_scheduled=rec.n_scheduled, latency=rec.latency,
+                    payload_bits=rec.payload_bits,
+                )
+                if mcfg.enabled:
+                    row.update(host_metrics[n])
+                self.ledger.round_row(n, **row)
+            self.ledger.timing("run", whole.seconds, entry="run_host_policy",
+                               rounds=int(n_rounds))
+        return result
+
+    def _host_rounds(self, policy, n_rounds: int, channel: str,
+                     with_eval: bool) -> tuple[list, list]:
+        """The rounds of :meth:`run_host_policy`: ``(records, per-round
+        telemetry rows)``."""
         exec_round = self._exec_fn(with_eval)
         data = self.data()
         mcfg = self.metrics_cfg
@@ -1185,7 +1254,6 @@ class FleetSim:
         # per-round telemetry rows of this replay (same schema as the
         # compiled taps; kept for the parity suite and the ledger)
         host_metrics: list[dict] = []
-        t_run0 = time.perf_counter()
         cum = 0.0
         for n in range(n_rounds):
             if channel == "sim":
@@ -1345,22 +1413,7 @@ class FleetSim:
                 dl_prev_host = float(dl_next)
         self.final_flat = flat
         self.last_host_metrics = host_metrics if mcfg.enabled else None
-        run_s = time.perf_counter() - t_run0
-        result = ExperimentResult(getattr(policy, "name", "host_policy"), records)
-        if self.ledger.enabled:
-            self._ledger_header("run_host_policy", n_rounds)
-            for n, rec in enumerate(records):
-                row = dict(
-                    energy=rec.energy, accuracy=rec.accuracy, loss=rec.loss,
-                    n_scheduled=rec.n_scheduled, latency=rec.latency,
-                    payload_bits=rec.payload_bits,
-                )
-                if mcfg.enabled:
-                    row.update(host_metrics[n])
-                self.ledger.round_row(n, **row)
-            self.ledger.timing("run", run_s, entry="run_host_policy",
-                               rounds=int(n_rounds))
-        return result
+        return records, host_metrics
 
     # -------------------------------------------------------------- sharding
 
@@ -1391,6 +1444,7 @@ class FleetSim:
 
 # ------------------------------------------------------------------- build
 
+@span("sim.build")
 def build_sim(
     task: str = "tiny",
     *,
@@ -1461,12 +1515,13 @@ def build_sim(
 
     task_spec, cnn_cfg, sysp = TASKS[task]
     mu, beta = task_data_sizes(task, mu, beta)
-    img_task = SyntheticImageTask(task_spec, seed=seed)
-    sizes = gaussian_sizes(n_clients, mu, beta, seed=seed)
-    datasets = make_federated_datasets(img_task, n_clients, sizes,
-                                      alpha=alpha_dirichlet, seed=seed)
+    with span("sim.data"):
+        img_task = SyntheticImageTask(task_spec, seed=seed)
+        sizes = gaussian_sizes(n_clients, mu, beta, seed=seed)
+        datasets = make_federated_datasets(img_task, n_clients, sizes,
+                                           alpha=alpha_dirichlet, seed=seed)
+        test = make_test_set(img_task, n=n_test, seed=seed + 999)
     fleet = build_fleet(datasets)
-    test = make_test_set(img_task, n=n_test, seed=seed + 999)
 
     loss_fn = functools.partial(cnn.loss_fn, cnn_cfg)
     params = cnn.init_params(cnn_cfg, jax.random.PRNGKey(seed))
@@ -1476,34 +1531,38 @@ def build_sim(
         return cnn.eval_metrics(cnn_cfg, unravel(flat), test_set["x"],
                                 test_set["y"])
 
-    ch_params = scenario.channel if scenario is not None else ChannelParams(
-        n_clients=n_clients, n_channels=n_channels
-    )
-    if scenario is None or scenario.topology.mode == "single_bs":
-        # legacy path: numpy drop + numpy probe — bit-for-bit the
-        # pre-scenario engine (golden-regressed in tests/test_scenario.py)
-        host_channel = ChannelModel(ch_params, seed=seed)
-        channel = SimChannel.from_host_model(host_channel)
-        if scenario is not None:
-            channel = dataclasses.replace(
-                channel, association=scenario.topology.association
-            )
-        probe_rates = host_channel.draw_rates()
-    else:
-        host_channel = None
-        drop_key = jax.random.fold_in(jax.random.PRNGKey(seed), DROP_KEY_TAG)
-        channel = SimChannel.from_topology(drop_key, ch_params,
-                                           scenario.topology)
-        probe_key = jax.random.fold_in(jax.random.PRNGKey(seed), PROBE_KEY_TAG)
-        probe_rates = np.asarray(channel.draw_rates(probe_key), np.float64)
+    with span("sim.channel"):
+        ch_params = (scenario.channel if scenario is not None
+                     else ChannelParams(n_clients=n_clients,
+                                        n_channels=n_channels))
+        if scenario is None or scenario.topology.mode == "single_bs":
+            # legacy path: numpy drop + numpy probe — bit-for-bit the
+            # pre-scenario engine (golden-regressed in tests/test_scenario.py)
+            host_channel = ChannelModel(ch_params, seed=seed)
+            channel = SimChannel.from_host_model(host_channel)
+            if scenario is not None:
+                channel = dataclasses.replace(
+                    channel, association=scenario.topology.association
+                )
+            probe_rates = host_channel.draw_rates()
+        else:
+            host_channel = None
+            drop_key = jax.random.fold_in(jax.random.PRNGKey(seed),
+                                          DROP_KEY_TAG)
+            channel = SimChannel.from_topology(drop_key, ch_params,
+                                               scenario.topology)
+            probe_key = jax.random.fold_in(jax.random.PRNGKey(seed),
+                                           PROBE_KEY_TAG)
+            probe_rates = np.asarray(channel.draw_rates(probe_key),
+                                     np.float64)
 
-    z = int(_flat0.shape[0])
-    probe = RoundContext(
-        rates=probe_rates, d_sizes=sizes.astype(np.float64),
-        g_sq=np.full(n_clients, 1.0), sigma_sq=np.full(n_clients, 1.0),
-        theta_max=np.full(n_clients, 1.0), z=z,
-    )
-    eps1, eps2 = auto_epsilons(probe, sysp, target_q=target_q)
+        z = int(_flat0.shape[0])
+        probe = RoundContext(
+            rates=probe_rates, d_sizes=sizes.astype(np.float64),
+            g_sq=np.full(n_clients, 1.0), sigma_sq=np.full(n_clients, 1.0),
+            theta_max=np.full(n_clients, 1.0), z=z,
+        )
+        eps1, eps2 = auto_epsilons(probe, sysp, target_q=target_q)
 
     hetero = None
     if hetero_weight > 0.0:

@@ -36,7 +36,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.fl.client import sgd_scan_body
-from repro.obs.profile import scope as _profile_scope
+from repro.obs.profile import scope as _profile_scope, span
 
 Pytree = Any
 LANES = 128
@@ -83,18 +83,17 @@ def build_fleet(datasets: list[dict]) -> Fleet:
     u = len(datasets)
     sample_shape = tuple(datasets[0]["x"].shape[1:])
     row = n_max * math.prod(sample_shape)
-    xs = np.zeros((u, -(-row // LANES) * LANES), np.float32)
-    ys = np.zeros((u, n_max), np.int32)
-    for i, d in enumerate(datasets):
-        xs[i, : d["x"].size] = d["x"].reshape(-1)
-        ys[i, : sizes[i]] = d["y"]
-    return Fleet(
-        x=jnp.asarray(xs),
-        y=jnp.asarray(ys),
-        n_samples=jnp.asarray(sizes, jnp.int32),
-        d_sizes=sizes,
-        sample_shape=sample_shape,
-    )
+    with span("sim.fleet_stack"):
+        xs = np.zeros((u, -(-row // LANES) * LANES), np.float32)
+        ys = np.zeros((u, n_max), np.int32)
+        for i, d in enumerate(datasets):
+            xs[i, : d["x"].size] = d["x"].reshape(-1)
+            ys[i, : sizes[i]] = d["y"]
+    with span("sim.fleet_place"):
+        x, y, n = jax.block_until_ready(
+            (jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(sizes, jnp.int32)))
+    return Fleet(x=x, y=y, n_samples=n, d_sizes=sizes,
+                 sample_shape=sample_shape)
 
 
 def _slice_rows(x: jax.Array, rows: jax.Array) -> jax.Array:
