@@ -208,7 +208,8 @@ def solve_kkt(
     5, grid fallback), then Theorem-3 floor/ceil integerization clamped to
     ``q_cap``. ``q_cont`` is the continuous clipped q_hat the
     integerization started from (the telemetry tap behind
-    ``RoundMetrics.q_cont_mean``). Everything is elementwise over U.
+    ``RoundMetrics.q_cont_mean``). Everything is elementwise over the
+    clients given; :func:`finish_decision` gives the scheduled ones only.
     """
     p, V = sysp.p_tx, v_weight
     L = sysp.lipschitz
@@ -373,6 +374,50 @@ def participation_from_assign(assign: jax.Array, rates: jax.Array):
     return v_assigned, onehot.any(axis=1)
 
 
+def solve_scheduled(
+    assign: jax.Array,     # (C,) channel -> client (-1 unused)
+    v: jax.Array,          # (U,) assigned uplink rate
+    w: jax.Array,          # (U,) round weights
+    d: jax.Array,          # (U,) dataset sizes
+    theta: jax.Array,      # (U,) theta_max
+    lam: jax.Array,        # scalar
+    sysp: SystemParams,
+    z: int,
+    v_weight: float,
+    q_cap: int = 8,
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """:func:`solve_kkt` for the clients ``assign`` schedules, as (U,) arrays.
+
+    Every output of :func:`finish_decision` is masked by participation, so
+    the solve runs on the slot axis of S = min(U, C) entries: the channel
+    axis when C < U (a free channel's slot is solved on zeros and dropped),
+    else the client axis. Gather and scatter are sums over the (U, C)
+    one-hot with one non-zero term, exact for finite values and cheaper on
+    the TPU than an indexed gather; the assignment is injective, as
+    :func:`greedy_assign` and the GA's repair leave it. Clients off the
+    assignment get q = 0, f = 0, feasible False and q_hat 0, as in
+    :func:`finish_host`.
+    """
+    u, c = v.shape[0], assign.shape[0]
+    onehot = assign[None, :] == jnp.arange(u)[:, None]  # -1 matches no client
+    if u <= c:
+        out = solve_kkt(v, w, d, theta, lam, sysp, z, v_weight, q_cap=q_cap)
+        sched = onehot.any(axis=1)
+        return tuple(jnp.where(sched, y, jnp.zeros((), y.dtype)) for y in out)
+
+    def gather(x):
+        return jnp.sum(jnp.where(onehot, x[:, None], 0.0), axis=0)
+
+    def scatter(y):
+        if y.dtype == jnp.bool_:
+            return jnp.any(onehot & y[None, :], axis=1)
+        return jnp.sum(jnp.where(onehot, y[None, :], jnp.zeros((), y.dtype)), axis=1)
+
+    out = solve_kkt(gather(v), gather(w), gather(d), gather(theta), lam, sysp,
+                    z, v_weight, q_cap=q_cap)
+    return tuple(scatter(y) for y in out)
+
+
 def finish_decision(
     assign: jax.Array,     # (C,) channel -> client (-1 unused)
     v_assigned: jax.Array, # (U,) assigned uplink rate
@@ -416,9 +461,9 @@ def finish_decision(
     w_full = d_sizes / jnp.sum(d_sizes)
 
     with _profile_scope("kkt_solve"):
-        q_int, f_int, feas, q_hat = solve_kkt(
-            v_assigned, w_round, d_sizes, theta_max, lam2, sysp, z, v_weight,
-            q_cap=q_cap,
+        q_int, f_int, feas, q_hat = solve_scheduled(
+            assign, v_assigned, w_round, d_sizes, theta_max, lam2, sysp, z,
+            v_weight, q_cap=q_cap,
         )
     # feas == a's gate except in float corner cases; fold it in so q/f/energy
     # stay consistent (w_round keeps the pre-solve participation, as the

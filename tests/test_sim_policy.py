@@ -7,6 +7,7 @@ clients as the numpy oracle that routes through the trusted scalar
 """
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro.core import kkt
@@ -142,3 +143,86 @@ def test_bound_terms_match_numpy_reference():
                                    jnp.asarray(q, jnp.int32)))
     assert dt_j == pytest.approx(dt_np, rel=1e-5)
     assert qt_j == pytest.approx(qt_np, rel=1e-5)
+
+
+def _fleet_wide_solve(assign, v, w, d, theta, lam, sysp, z, v_weight, q_cap=8):
+    """The KKT on every client: the reference that the slot-axis solve
+    must equal wherever a > 0."""
+    del assign
+    return policy.solve_kkt(v, w, d, theta, lam, sysp, z, v_weight, q_cap=q_cap)
+
+
+def _chromosome(rng, u, c, n_sched):
+    assign = np.full(c, -1, np.int32)
+    chans = rng.permutation(c)[:n_sched]
+    assign[chans] = rng.permutation(u)[:n_sched]
+    return assign
+
+
+@pytest.mark.parametrize("case,u,c", [
+    ("greedy", 32, 8),       # U > C, every channel used
+    ("chromosome", 32, 8),   # random GA chromosome with -1 channels
+    ("partial", 32, 8),      # two of eight channels used
+    ("dropped", 32, 8),      # a scheduled client fails the feasibility gate
+    ("population", 32, 8),   # the GA fitness: vmapped over chromosomes
+    ("greedy", 5, 9),        # U <= C: the slot axis is the client axis
+    ("chromosome", 6, 6),    # U == C with free channels
+])
+def test_slot_axis_solve_equals_fleet_wide_solve(monkeypatch, case, u, c):
+    """``finish_decision`` solves the KKT only for the clients an assignment
+    schedules; every output equals the fleet-wide solve masked by
+    participation, bit for bit (``q_cont`` where a > 0, its contract)."""
+    z = 246590
+    rng = np.random.default_rng(u * 31 + c)
+    rates = ChannelModel(ChannelParams(n_clients=u, n_channels=c), seed=u + c).draw_rates()
+    if case == "dropped":
+        rates[:, :] = np.maximum(rates, 1e8)
+        rates[3, :] = 1e6   # cannot carry q = 1 within T_max
+    d = np.maximum(rng.normal(1200, 300, u), 50)
+    g = rng.uniform(0.5, 2.0, u); g /= g.mean()
+    s = rng.uniform(0.5, 2.0, u); s /= s.mean()
+    th = rng.uniform(0.2, 1.5, u)
+    ctx = [jnp.asarray(x, jnp.float32) for x in (rates, d, g, s, th)]
+    if case == "greedy":
+        assigns = np.asarray(policy.greedy_assign(ctx[0]))[None]
+    elif case == "partial":
+        assigns = _chromosome(rng, u, c, 2)[None]
+    elif case == "dropped":
+        assigns = _chromosome(rng, u, c, c)[None]
+        assigns[0, 1] = 3
+    elif case == "population":
+        assigns = np.stack([_chromosome(rng, u, c, int(n))
+                            for n in rng.integers(1, c + 1, 6)])
+    else:
+        assigns = _chromosome(rng, u, c, min(u, c) - 2)[None]
+
+    def finish(assign, rates, d, g, s, th):
+        v_assigned, a0 = policy.participation_from_assign(assign, rates)
+        return policy.finish_decision(
+            assign, v_assigned, a0, d, g, s, th, jnp.float32(80.0), SYSP, z,
+            100.0,
+        )
+
+    # the solve under test runs vmapped, as the GA fitness runs it; the
+    # reference is jitted per assignment, since the vmapped fleet-wide solve
+    # rounds some frequencies one ulp away from the unbatched one on the CPU
+    got = jax.jit(jax.vmap(finish, in_axes=(0,) + (None,) * 5))(
+        jnp.asarray(assigns), *ctx)
+    with monkeypatch.context() as m:
+        m.setattr(policy, "solve_scheduled", _fleet_wide_solve)
+        ref = jax.jit(finish)
+        want = jax.tree.map(lambda *xs: np.stack(xs),
+                            *[ref(jnp.asarray(x), *ctx) for x in assigns])
+    a = np.asarray(want.a) > 0
+    assert a.any()
+    if case == "dropped":
+        assert not a[0, 3]
+    for name in ("assign", "slots", "a", "q", "f", "v_assigned", "energy",
+                 "latency", "data_term", "quant_term", "payload_bits"):
+        np.testing.assert_array_equal(np.asarray(getattr(got, name)),
+                                      np.asarray(getattr(want, name)), name)
+    np.testing.assert_array_equal(np.asarray(got.q_cont)[a],
+                                  np.asarray(want.q_cont)[a])
+    # off the assignment nothing is solved: q_hat reads 0, as in finish_host
+    on = (assigns[:, None, :] == np.arange(u)[None, :, None]).any(axis=2)
+    np.testing.assert_array_equal(np.asarray(got.q_cont)[~on], 0.0)

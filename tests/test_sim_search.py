@@ -199,6 +199,26 @@ def test_engine_ga_mode_one_compile():
     assert len(lowered.as_text()) > 0
 
 
+def test_ga_kkt_grid_is_slot_bound():
+    """The GA fitness solves the KKT on the slot axis: its 512-point grid
+    is (S, 512) per chromosome, S = min(U, C), and no fleet-width (U, 512)
+    grid survives into the lowered decision at C << U."""
+    u, c = 64, 4
+    rates, d, g, s, th = _context(u, c, 0)
+    cfg = GAConfig(generations=2, population=8, elitism=2)
+    fn = jax.jit(functools.partial(
+        search.ga_decide, sysp=SYSP, z=246590, v_weight=100.0, cfg=cfg
+    ))
+    txt = fn.lower(
+        jax.random.PRNGKey(0), *(jnp.asarray(x, jnp.float32)
+                                 for x in (rates, d, g, s, th)),
+        lam1=jnp.float32(10.0), lam2=jnp.float32(60.0),
+    ).as_text()
+    assert f"{u}x512x" not in txt
+    assert f"tensor<{cfg.population}x{c}x512xf32>" in txt  # the fitness
+    assert f"tensor<{c}x512xf32>" in txt                   # the winner
+
+
 # -------------------------------------------------- operator property tests
 
 def _random_maybe_invalid(seed, u, c):
