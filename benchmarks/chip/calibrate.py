@@ -9,8 +9,9 @@ stand-ins for the program against the same reference: the control
 never moves; ``half_batch``: local SGD on half of each batch;
 ``altered``: one scheduled client's quantization level changed). Prints
 one JSON line per seed with every number of ``checks.NUMBERS``, and the
-followed rounds' test loss of the program and of the reference.
-Needs the chip, like ``run.py``.
+followed rounds' test loss of the program and of the reference. For
+cells of the fleet-scan runner (``runners/fleet_scan.py``); needs the
+chip, like ``run.py``.
 """
 import json
 import pathlib
@@ -42,20 +43,15 @@ def main() -> int:
     import gc
 
     import jax
-    import numpy as np
-
-    from repro.sim import build_sim
 
     harness.device_info(True, cell.chips)
-    cfg, tr = cell.config, cell.traffic
+    cfg, tr, fleet = cell.config, cell.traffic, cell.runner
     n = args.rounds or tr["reference_rounds"]
     for arg in (int(s) for s in args.seeds.split(",")):
-        seed = harness.program_seed(cfg, arg)
-        sim = build_sim(cfg["task"], **harness._sim_kwargs(cell, seed))
-        res = sim.run_compiled(tr["rounds_per_experiment"],
-                               with_eval=tr["with_eval"])
-        prog = {k: np.asarray(getattr(res, k)) for k in checks.OUTPUTS}
-        del sim, res
+        built = fleet.build(cell, arg)
+        seed = built.seed
+        prog = built.outputs(built.experiment())
+        del built
         gc.collect()
         jax.clear_caches()
         follows = tr["policy"] == "compiled-ga"

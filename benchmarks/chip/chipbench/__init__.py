@@ -1,1 +1,1 @@
-"""The chip benchmark of the compiled fleet scan (see ../README.md)."""
+"""The chip benchmark's harness and yardstick (see ../README.md)."""

@@ -1,4 +1,5 @@
-"""What decides ``correct``: the program's outputs against the plain
+"""What decides ``correct`` in the fleet scan's cells
+(``runners/fleet_scan.py``): the program's outputs against the plain
 reference (``chipbench.reference``), number by number, each against the
 cell's limit; and the structural checks every experiment of a window
 must pass."""
@@ -51,11 +52,6 @@ def compare(prog: dict, ref: dict) -> dict:
         "loss_gap": float(np.max(np.abs(p["loss"] - ref["loss"])
                                  / np.abs(ref["loss"]))),
     }
-
-
-def within(numbers: dict, limits: dict) -> bool:
-    """Every number at or under its limit (a NaN never is)."""
-    return all(numbers[k] <= limits[k] for k in NUMBERS)
 
 
 def structural_faults(res, n_rounds: int, u: int, c: int, q_cap: int) -> list:

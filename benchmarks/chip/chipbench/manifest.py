@@ -1,7 +1,8 @@
 """The benchmark as data: ``BENCHMARK.json`` at the repository root names
-the cells and metrics; each configuration, traffic mix, cell and
-per-layer metric lives in a file of its own under ``benchmarks/chip``,
-found by its name. Adding one is adding a file and an entry."""
+the cells and metrics; each configuration, traffic mix, cell, per-layer
+metric and runner (the program a configuration's cells run) lives in a
+file of its own under ``benchmarks/chip``, found by its name. Adding one
+is adding a file and an entry."""
 from __future__ import annotations
 
 import dataclasses
@@ -9,6 +10,7 @@ import importlib.util
 import json
 import pathlib
 import re
+import types
 from typing import Callable, Optional
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parents[1]
@@ -31,6 +33,7 @@ class Cell:
     limits: dict       # workloads/<name>.json["limits"]
     end_to_end: list   # BENCHMARK.json end_to_end entries this cell reports
     per_layer: list    # BENCHMARK.json per_layer entries this cell reports
+    runner: types.ModuleType  # runners/<config["runner"]>.py
 
 
 class Manifest:
@@ -53,28 +56,54 @@ class Manifest:
         return "workloads" not in metric or cell in metric["workloads"]
 
     def cell(self, name: str) -> Cell:
+        """The cell's files, read and checked: its configuration names a
+        runner that exists and accepts it, and its limits name exactly
+        the runner's ``NUMBERS``."""
         w = self._entry("workloads", name)
         conf = self._entry("configs", w["config"])
+        config = _load_json(self.root / conf["file"])
+        if "runner" not in config:
+            raise KeyError(f"configuration {conf['name']!r} names no runner")
+        runner = self.runner(config["runner"])
+        runner.check_config(config)
+        limits = _load_json(
+            self.bench_dir / "workloads" / f"{name}.json")["limits"]
+        if set(limits) != set(runner.NUMBERS):
+            raise ValueError(f"cell {name!r} has limits for {sorted(limits)}; "
+                             f"its runner compares {list(runner.NUMBERS)}")
         return Cell(
             name=name,
             chips=int(w["chips"]),
-            config=_load_json(self.root / conf["file"]),
+            config=config,
             traffic=_load_json(self.bench_dir / "traffic" / f"{w['traffic']}.json"),
-            limits=_load_json(self.bench_dir / "workloads" / f"{name}.json")["limits"],
+            limits=limits,
             end_to_end=[m for m in self.data["end_to_end"]
                         if self.reports(m, name)],
             per_layer=[m for m in self.data["per_layer"]
                        if self.reports(m, name)],
+            runner=runner,
         )
 
     def reader(self, metric: str) -> Callable:
         """``read(ctx) -> float | None`` of ``metrics/<metric>.py``."""
-        path = self.bench_dir / "metrics" / f"{metric}.py"
+        return self._module("metrics", metric).read
+
+    def runner(self, name: str) -> types.ModuleType:
+        """``runners/<name>.py``: ``NUMBERS``, ``check_config(cfg)``,
+        ``build(cell, seed)`` and ``check(cell, seed, outputs)`` (see
+        ``chipbench.harness``)."""
+        return self._module("runners", name)
+
+    def _module(self, kind: str, name: str) -> types.ModuleType:
+        """``<kind>/<name>.py`` under the benchmark, loaded by its path."""
+        path = self.bench_dir / kind / f"{name}.py"
+        if not path.is_file():
+            raise KeyError(f"no {kind}/{name}.py in {self.bench_dir}")
         spec = importlib.util.spec_from_file_location(
-            f"chipbench_metric_{metric.replace('.', '_')}", path)
+            f"chipbench_{kind}_{name.replace('.', '_')}", path)
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
-        return mod.read
+        return mod
 
 
 def load_peaks(device_kind: str, path: Optional[pathlib.Path] = None) -> dict:

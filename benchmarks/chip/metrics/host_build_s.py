@@ -1,5 +1,6 @@
-"""Set-up: seconds of ``repro.sim.build_sim`` until the fleet and the test
-set are on the device (host data generation and placement)."""
+"""Set-up: seconds of the runner's ``build`` until the program's inputs
+are on the device (for the fleet scan ``repro.sim.build_sim``: host data
+generation and placement of the fleet and the test set)."""
 
 
 def read(ctx):

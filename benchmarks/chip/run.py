@@ -1,4 +1,4 @@
-"""The chip benchmark of the compiled QCCF fleet scan: one run of one cell.
+"""The chip benchmark: one run of one cell.
 
     python3 benchmarks/chip/run.py --workload <name> --seed <n> \
         --seconds <s> --trace <0|1>
@@ -35,7 +35,9 @@ def main() -> int:
     from repro.launch.compile_cache import enable_compile_cache
 
     mf = manifest.Manifest(ROOT)
-    mf.cell(args.workload)  # an unknown cell fails before JAX starts
+    # an unknown cell, or a configuration naming no known runner, fails
+    # here, before JAX starts
+    mf.cell(args.workload)
     enable_compile_cache()
     harness.configure_cache()
     try:

@@ -10,6 +10,8 @@ BENCH = pathlib.Path(__file__).resolve().parents[1]
 ROOT = BENCH.parents[1]
 
 TINY_CELL = "tiny_u16.greedy"
+# the keys of a ``--trace 0`` result line, in order, whatever the runner
+RESULT_KEYS = ["correct", "attempted", "failed", "metrics", "device", "checks"]
 
 
 def tiny_config() -> dict:

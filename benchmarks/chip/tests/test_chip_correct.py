@@ -14,8 +14,8 @@ import pytest
 
 from chipbench import checks, harness, manifest, reference
 
-from chip_fixtures import (BENCH, ROOT, TINY_CELL, TINY_GA, TINY_LIMITS,
-                           make_root)
+from chip_fixtures import (BENCH, RESULT_KEYS, ROOT, TINY_CELL, TINY_GA,
+                           TINY_LIMITS, make_root)
 
 
 def _run(root, seed=3):
@@ -28,7 +28,7 @@ def test_sound_program_is_correct(tiny_root):
     out = _run(tiny_root)
     assert out["correct"], out["checks"]
     assert out["attempted"] >= 1 and out["failed"] == 0
-    assert list(out)[-1] == "checks"
+    assert list(out) == RESULT_KEYS
     assert set(out["metrics"]) == {"rounds_per_s", "setup_s", "peak_hbm_gb"}
 
 
@@ -42,7 +42,7 @@ def test_sound_genetic_search_is_correct(tmp_path):
     for kw in ({"precision": "bfloat16"}, {"fault": "altered"}):
         bad = reference.Reference(cell.config, cell.traffic, 11, **kw).run(3)
         judge = reference.Reference(cell.config, cell.traffic, 11, follow=bad)
-        assert not checks.within(checks.compare(bad, judge.run(3)),
+        assert not harness.within(checks.compare(bad, judge.run(3)),
                                  cell.limits), kw
 
 
@@ -52,7 +52,7 @@ def test_control_fails(tiny_root):
     sound = reference.Reference(cell.config, cell.traffic, 5).run(3)
     control = reference.Reference(cell.config, cell.traffic, 5,
                                   precision="bfloat16").run(3)
-    assert not checks.within(checks.compare(control, sound), cell.limits)
+    assert not harness.within(checks.compare(control, sound), cell.limits)
 
 
 def _half_batch(orig):

@@ -1,6 +1,5 @@
 """The readers of the round's layers (``repro.obs.profile.ROUND_SCOPES``)
 on a small built trace that carries every layer's scope."""
-import numpy as np
 import pytest
 
 from chipbench import harness, manifest, tracing
@@ -85,11 +84,14 @@ def built_trace(drop: str = "") -> tracing.Trace:
 def _read(metric: str, tr: tracing.Trace):
     mf = manifest.Manifest(ROOT)
     lo, hi = 0.0, 10 * MS
+    cell = mf.cell("femnist_u1024.greedy")
     ctx = harness.LayerContext(
-        cell=mf.cell("femnist_u1024.greedy"), spans={}, trace=tr,
+        cell=cell, spans={}, trace=tr,
         window=(lo, hi), window_s=0.01,
         busy_s=tracing.busy_ns(tr, lo, hi) / 1e9, rounds=2,
-        scheduled=np.array([8, 8]), peaks=manifest.load_peaks("TPU v5 lite"))
+        useful_flops=cell.runner.useful_flops(cell.config, cell.traffic,
+                                              [8, 8]),
+        peaks=manifest.load_peaks("TPU v5 lite"))
     return mf.reader(metric)(ctx)
 
 

@@ -1,7 +1,6 @@
 """The trace reduction and the per-layer readers on a small built trace."""
 import json
 
-import numpy as np
 import pytest
 
 from chipbench import flops, harness, manifest, tracing
@@ -103,12 +102,15 @@ def test_top_ops_are_leaves_sorted_by_device_time():
 def _ctx(tr):
     mf = manifest.Manifest(ROOT)
     lo, hi = 0.0, 10 * MS
+    cell = mf.cell("femnist_u1024.greedy")
     return mf, harness.LayerContext(
-        cell=mf.cell("femnist_u1024.greedy"),
+        cell=cell,
         spans={"host_build": 31.0, "compile": 2.5}, trace=tr,
         window=(lo, hi), window_s=0.01,
         busy_s=tracing.busy_ns(tr, lo, hi) / 1e9, rounds=2,
-        scheduled=np.array([8, 8]), peaks=manifest.load_peaks("TPU v5 lite"),
+        useful_flops=cell.runner.useful_flops(cell.config, cell.traffic,
+                                              [8, 8]),
+        peaks=manifest.load_peaks("TPU v5 lite"),
         memory={"argument": 5_300_000_000, "output": 2_000_000,
                 "alias": 1_000_000, "temp": 300_000_000})
 
